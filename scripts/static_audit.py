@@ -56,7 +56,6 @@ def audit_matrix(arch: str, mesh, ctx, *, stream=sys.stdout) -> bool:
     import jax
     import jax.numpy as jnp
 
-    from repro import compat
     from repro.models.model import Model
     from repro.serving import Engine
     from repro.staticcheck import audit_engine
@@ -65,7 +64,7 @@ def audit_matrix(arch: str, mesh, ctx, *, stream=sys.stdout) -> bool:
     model = Model(cfg)
     params = model.init_params(jax.random.PRNGKey(0))
     ok = True
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for label, cache_spec, token_budget in ENGINE_MATRIX:
             kw = {} if token_budget is None else {"token_budget": token_budget}
             eng = Engine(model, params, ctx, max_slots=2, max_len=64,
@@ -87,11 +86,11 @@ def _reduced_cfg(arch: str):
 
 def run_local(arch: str) -> bool:
     """1-device TP mesh: real axis semantics without a multidevice runtime."""
-    from repro import compat
     from repro.core.policy import PAPER_DEFAULT
     from repro.core.tp import TPContext
+    from repro.launch.mesh import make_mesh
 
-    mesh = compat.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     ctx = TPContext(mesh=mesh, data_axes=(), policy=PAPER_DEFAULT)
     print("== jaxpr audit: 1-device TP mesh, policy "
           f"{PAPER_DEFAULT.describe()} ==\n")
@@ -104,15 +103,15 @@ def run_tp_subprocess(arch: str) -> bool:
     script = (
         "import sys; sys.path.insert(0, 'src')\n"
         "from scripts.static_audit import audit_matrix\n"
-        "from repro import compat\n"
+        "from repro.launch.mesh import make_mesh\n"
         "from repro.launch.sharding import make_context\n"
         "from repro.core.policy import PAPER_DEFAULT\n"
-        "mesh = compat.make_mesh((2, 4), ('data', 'model'))\n"
+        "mesh = make_mesh((2, 4), ('data', 'model'))\n"
         "ctx = make_context(mesh, None, policy=PAPER_DEFAULT)\n"
         f"ok = audit_matrix({arch!r}, mesh, ctx)\n"
         "sys.exit(0 if ok else 1)\n"
     )
-    env = dict(os.environ, PYTHONPATH="src",
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8")
     print("== jaxpr audit: subprocess data(2) x model(4) mesh ==\n",
           flush=True)
@@ -134,15 +133,15 @@ def run_kv_subprocess(arch: str) -> bool:
     script = (
         "import sys; sys.path.insert(0, 'src')\n"
         "from scripts.static_audit import audit_matrix\n"
-        "from repro import compat\n"
+        "from repro.launch.mesh import make_mesh\n"
         "from repro.launch.sharding import make_context\n"
         "from repro.core.policy import PAPER_DEFAULT\n"
-        "mesh = compat.make_mesh((2, 2, 2), ('kv', 'data', 'model'))\n"
+        "mesh = make_mesh((2, 2, 2), ('kv', 'data', 'model'))\n"
         "ctx = make_context(mesh, None, policy=PAPER_DEFAULT, kv_axis='kv')\n"
         f"ok = audit_matrix({arch!r}, mesh, ctx)\n"
         "sys.exit(0 if ok else 1)\n"
     )
-    env = dict(os.environ, PYTHONPATH="src",
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8")
     print("== jaxpr audit: subprocess kv(2) x data(2) x model(2) mesh "
           "(sequence-sharded pools) ==\n", flush=True)

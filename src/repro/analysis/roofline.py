@@ -90,9 +90,7 @@ def model_flops(cfg, tokens: int, *, train: bool) -> float:
 
 def analyze_compiled(compiled, *, n_chips: int, cfg=None, tokens: int = 0,
                      train: bool = False) -> Dict:
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # JAX 0.4.x: one dict per device set
-        cost = cost[0] if cost else {}
+    cost = compiled.cost_analysis() or {}
     flops = float(cost.get("flops", 0.0))
     bytes_accessed = float(cost.get("bytes accessed", 0.0))
     hlo = compiled.as_text()

@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.collectives import masked_owner_psum, psum_maybe_compressed
 from repro.core.policy import CompressionPolicy, NO_COMPRESSION
 
@@ -230,7 +229,7 @@ def row_linear(
                                      axis_size=tp_size)
 
     mids = [None] * (x.ndim - 2)
-    y = shard_map(
+    y = jax.shard_map(
         island,
         mesh=ctx.mesh,
         in_specs=(P(b_entry, *mids, axis), P(axis, None)),
@@ -297,7 +296,7 @@ def fused_mlp(
     w_specs = (P(None, axis),) * (2 if has_gate else 1) + (P(axis, None),)
     args = ((w_gate, w_up, w_down) if has_gate else (w_up, w_down))
     mids = [None] * (x.ndim - 2)
-    return shard_map(
+    return jax.shard_map(
         island,
         mesh=ctx.mesh,
         in_specs=(P(b_entry, *mids, None), *w_specs),
@@ -370,7 +369,7 @@ def pool_exchange(ctx: TPContext, pools, tables: jnp.ndarray):
             masked_owner_psum(slab[local], own, kv) for slab in slabs
         )
 
-    return list(shard_map(
+    return list(jax.shard_map(
         island,
         mesh=ctx.mesh,
         in_specs=(P(None, None),) + tuple(P(kv, None, m) for m in m_entries),
@@ -407,7 +406,7 @@ def pool_scatter(ctx: TPContext, pools_vals, blk: jnp.ndarray,
     pool_specs = tuple(P(kv, None, m) for m in m_entries)
     val_specs = tuple(P(None, m) for m in m_entries)
     flat = [p for p, _ in pools_vals] + [v for _, v in pools_vals]
-    return list(shard_map(
+    return list(jax.shard_map(
         island,
         mesh=ctx.mesh,
         in_specs=(P(None), P(None)) + pool_specs + val_specs,
@@ -436,7 +435,7 @@ def pool_block_write(ctx: TPContext, pools_vals, block_ids: jnp.ndarray):
     pool_specs = tuple(P(kv, None, m) for m in m_entries)
     val_specs = tuple(P(None, None, m) for m in m_entries)
     flat = [p for p, _ in pools_vals] + [v for _, v in pools_vals]
-    return list(shard_map(
+    return list(jax.shard_map(
         island,
         mesh=ctx.mesh,
         in_specs=(P(None),) + pool_specs + val_specs,
@@ -464,7 +463,7 @@ def pool_block_fill(ctx: TPContext, pools_fills, block: jnp.ndarray):
         )
 
     pool_specs = tuple(P(kv, None, m) for m in m_entries)
-    return list(shard_map(
+    return list(jax.shard_map(
         island,
         mesh=ctx.mesh,
         in_specs=(P(),) + pool_specs,
@@ -495,7 +494,7 @@ def pool_block_copy(ctx: TPContext, pools, src: jnp.ndarray,
         return tuple(outs)
 
     pool_specs = tuple(P(kv, None, m) for m in m_entries)
-    return list(shard_map(
+    return list(jax.shard_map(
         island,
         mesh=ctx.mesh,
         in_specs=(P(), P()) + pool_specs,

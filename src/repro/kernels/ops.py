@@ -6,8 +6,8 @@ leading dims are flattened to 2-D for the kernels; shapes that don't satisfy
 the tiling constraints fall back to the pure-jnp oracle (never wrong, just
 not the fast path).
 
-On CPU (this container) kernels run with interpret=True; on TPU they lower
-to Mosaic (interpret=False).
+On the CPU backend kernels run with interpret=True; on TPU they lower to
+Mosaic (interpret=False).
 """
 from __future__ import annotations
 
@@ -20,10 +20,11 @@ from repro.core import mx as _oracle
 from repro.kernels.mx_dequant import dequant_reduce, mx_dequantize_2d
 from repro.kernels.mx_quant import mx_quantize_2d
 
-__all__ = ["mx_quantize", "mx_dequantize", "mx_dequant_reduce"]
+__all__ = ["mx_quantize", "mx_dequantize", "mx_dequant_reduce", "interpret_mode"]
 
 
-def _interpret() -> bool:
+def interpret_mode() -> bool:
+    """Run Pallas kernels in interpret mode: on the CPU backend only."""
     return jax.default_backend() == "cpu"
 
 
@@ -39,7 +40,7 @@ def mx_quantize(x: jnp.ndarray, spec: MXSpec) -> MXCompressed:
     if m == 0 or not _can_tile(n, spec):
         return _oracle.quantize(x, spec)
     payload, scales = mx_quantize_2d(
-        x.reshape(m, n), spec, interpret=_interpret()
+        x.reshape(m, n), spec, interpret=interpret_mode()
     )
     return MXCompressed(
         payload=payload.reshape(*lead, payload.shape[-1]),
@@ -61,7 +62,7 @@ def mx_dequantize(comp: MXCompressed, spec: MXSpec, out_dtype=jnp.float32) -> jn
         comp.scales.reshape(m, comp.scales.shape[-1]),
         spec,
         out_dtype=out_dtype,
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )
     return out.reshape(*lead, n)
 
@@ -83,6 +84,6 @@ def mx_dequant_reduce(comp: MXCompressed, spec: MXSpec, out_dtype=jnp.float32) -
         comp.scales.reshape(s, m, comp.scales.shape[-1]),
         spec,
         out_dtype=out_dtype,
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )
     return out.reshape(*lead, n)

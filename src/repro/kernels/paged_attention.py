@@ -16,6 +16,12 @@ current step's compute-precision K/V (``k_extra``/``v_extra``: the prefill
 chunk's own tokens, or the mixed step's in-batch K/V) is folded in at the
 last block before normalization.
 
+Layout keeps Mosaic's tiling rules: queries enter pre-grouped per KV head
+as (rows, KV, Sq*G, hd) and leave the same way (the wrapper transposes), so
+the body never reshapes lanes; each KV head is a 128-aligned lane slice of
+the flat (block, kv_dim) pool block, attended with 2-D matmuls; query and
+extra positions arrive as whole-array-tail blocks ((Sq*G, 1) and (1, E)).
+
 Masking follows the same finite ``-1e30`` convention as
 ``models/attention.py``: initializing the running max at ``NEG_INF`` (not
 ``-inf``) makes a fully-masked row degrade to a uniform average over its
@@ -42,8 +48,8 @@ __all__ = ["paged_attention"]
 NEG_INF = -1e30
 
 
-def _kernel(tables_ref, hist_ref, *refs, spec, kv_heads, head_dim, q_heads,
-            seq_q, block_size, n_blocks, scale, window, has_extra,
+def _kernel(tables_ref, hist_ref, *refs, spec, kv_heads, head_dim,
+            block_size, n_blocks, scale, window, has_extra,
             has_row_map=False):
     if has_row_map:
         # third scalar-prefetch operand (the virtual-region row map) — only
@@ -64,8 +70,7 @@ def _kernel(tables_ref, hist_ref, *refs, spec, kv_heads, head_dim, q_heads,
     out_ref, m_scr, l_scr, acc_scr = refs[i:i + 4]
 
     r, j = pl.program_id(0), pl.program_id(1)
-    KV, G, hd = kv_heads, q_heads // kv_heads, head_dim
-    SqG = seq_q * G
+    hd = head_dim
 
     @pl.when(j == 0)
     def _init():
@@ -73,42 +78,36 @@ def _kernel(tables_ref, hist_ref, *refs, spec, kv_heads, head_dim, q_heads,
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    # (Sq, H*hd) -> (KV, Sq*G, hd): fold query heads into batched GQA groups
-    q = q_ref[0].astype(jnp.float32)
-    qg = q.reshape(seq_q, KV, G, hd).transpose(1, 0, 2, 3).reshape(KV, SqG, hd)
-    q_pos = qp_ref[0]                                          # (Sq,) int32
+    q_pos = qp_ref[0]                                  # (Sq*G, 1) int32
     hist = hist_ref[r]
 
-    def accumulate(k, v, t_valid):
-        """Fold one batch of keys into the online-softmax state. k/v are
-        (T', kv_dim) fp32; t_valid is the (Sq, T') mask."""
-        Tb = k.shape[0]
-        kh = k.reshape(Tb, KV, hd).transpose(1, 0, 2)          # (KV, T', hd)
-        vh = v.reshape(Tb, KV, hd).transpose(1, 0, 2)
-        s = jax.lax.dot_general(
-            qg, kh, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale        # (KV, SqG, T')
-        valid = jnp.broadcast_to(
-            t_valid[:, None, :], (seq_q, G, Tb)).reshape(1, SqG, Tb)
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[..., None])
-        l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1)
-        pv = jax.lax.dot_general(
-            p, vh, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)                # (KV, SqG, hd)
-        acc_scr[...] = acc_scr[...] * alpha[..., None] + pv
-        m_scr[...] = m_new
+    def accumulate(k, v, valid):
+        """Fold one batch of keys into the online-softmax state, one KV head
+        (a 128-aligned lane slice of the flat K/V) at a time. k/v are
+        (T', kv_dim) fp32; valid is the (Sq*G, T') mask."""
+        for h in range(kv_heads):                      # static unroll
+            kh = k[:, h * hd:(h + 1) * hd]
+            vh = v[:, h * hd:(h + 1) * hd]
+            s = jax.lax.dot_general(
+                q_ref[0, h].astype(jnp.float32), kh, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # (Sq*G, T')
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_scr[h]                          # (Sq*G, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_scr[h] = l_scr[h] * alpha + p.sum(axis=-1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + jnp.dot(
+                p, vh, preferred_element_type=jnp.float32)
+            m_scr[h] = m_new
 
     # this grid step's pool block: positions [j*bs, (j+1)*bs) of the row's
     # logical sequence, valid below the row's history end and causally
-    t_row = j * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (seq_q, block_size), 1)
-    tv = (t_row < hist) & (t_row <= q_pos[:, None])
+    t_blk = j * block_size + jax.lax.broadcasted_iota(
+        jnp.int32, (1, block_size), 1)
+    tv = (t_blk < hist) & (t_blk <= q_pos)
     if window is not None:
-        tv = tv & (t_row > q_pos[:, None] - window)
+        tv = tv & (t_blk > q_pos - window)
     if spec is None:
         k = k_ref[0].astype(jnp.float32)                       # (bs, kv_dim)
         v = v_ref[0].astype(jnp.float32)
@@ -120,16 +119,14 @@ def _kernel(tables_ref, hist_ref, *refs, spec, kv_heads, head_dim, q_heads,
     @pl.when(j == n_blocks - 1)
     def _finalize():
         if has_extra:
-            ke = ke_ref[...].astype(jnp.float32)               # (E, kv_dim)
-            ve = ve_ref[...].astype(jnp.float32)
-            te = te_ref[0]                                     # (E,) int32
-            ev = te[None, :] <= q_pos[:, None]                 # (Sq, E)
+            te = te_ref[0]                                     # (1, E) int32
+            ev = te <= q_pos                                   # (Sq*G, E)
             if window is not None:
-                ev = ev & (te[None, :] > q_pos[:, None] - window)
-            accumulate(ke, ve, ev)
-        out = acc_scr[...] / l_scr[...][..., None]             # (KV, SqG, hd)
-        out = out.reshape(KV, seq_q, G, hd).transpose(1, 0, 2, 3)
-        out_ref[...] = out.reshape(1, seq_q, KV * G * hd).astype(out_ref.dtype)
+                ev = ev & (te > q_pos - window)
+            accumulate(ke_ref[...].astype(jnp.float32),
+                       ve_ref[...].astype(jnp.float32), ev)
+        for h in range(kv_heads):
+            out_ref[0, h] = (acc_scr[h] / l_scr[h]).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -153,7 +150,7 @@ def paged_attention(
     scale: float,
     window=None,
     out_dtype=jnp.float32,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Gather-free paged GQA attention: walk each row's block table, stream
     pool blocks through VMEM with online softmax, fold in optional in-step
@@ -186,6 +183,7 @@ def paged_attention(
     hd = kv_dim // kv_heads
     H = q_dim // hd
     G = H // kv_heads
+    SqG = Sq * G
     has_extra = k_extra is not None
 
     has_rm = row_map is not None
@@ -194,74 +192,78 @@ def paged_attention(
     # specs index the pool by the row's table entry (replicated pools) or by
     # its virtual region (sharded exchange buffer) — one block DMA per step
     if has_rm:
-        def _q_map(r, j, tbl, hl, rm):
+        def _row(r, j, tbl, hl, rm):
             return (r, 0, 0)
 
-        def _blk_map(r, j, tbl, hl, rm):
+        def _blk(r, j, tbl, hl, rm):
             return (rm[r] * nb + j, 0, 0)
 
-        def _row_map(r, j, tbl, hl, rm):
-            return (r, 0)
-
-        def _whole_map(r, j, tbl, hl, rm):
+        def _whole(r, j, tbl, hl, rm):
             return (0, 0)
     else:
-        def _q_map(r, j, tbl, hl):
+        def _row(r, j, tbl, hl):
             return (r, 0, 0)
 
-        def _blk_map(r, j, tbl, hl):
+        def _blk(r, j, tbl, hl):
             return (tbl[r, j], 0, 0)
 
-        def _row_map(r, j, tbl, hl):
-            return (r, 0)
-
-        def _whole_map(r, j, tbl, hl):
+        def _whole(r, j, tbl, hl):
             return (0, 0)
 
-    in_specs = [pl.BlockSpec((1, Sq, q_dim), _q_map)]
-    operands = [q]
+    def _row4(*idx):
+        return _row(*idx) + (0,)
+
+    # every block's last two dims are whole array dims or (8, 128)
+    # multiples, as Mosaic requires: queries arrive pre-grouped per KV head
+    # as (R, KV, Sq*G, hd), their positions as a (Sq*G, 1) column per row,
+    # the extras' positions as a (1, E) row per row
+    qg = q.reshape(R, Sq, kv_heads, G, hd).transpose(0, 2, 1, 3, 4)
+    in_specs = [pl.BlockSpec((1, kv_heads, SqG, hd), _row4)]
+    operands = [qg.reshape(R, kv_heads, SqG, hd)]
     if spec is None:
-        in_specs += [pl.BlockSpec((1, bs, kv_dim), _blk_map),
-                     pl.BlockSpec((1, bs, kv_dim), _blk_map)]
+        in_specs += [pl.BlockSpec((1, bs, kv_dim), _blk),
+                     pl.BlockSpec((1, bs, kv_dim), _blk)]
         operands += [pool_k, pool_v]
     else:
         pb, sb = pool_k.payload.shape[-1], pool_k.scales.shape[-1]
-        in_specs += [pl.BlockSpec((1, bs, pb), _blk_map),
-                     pl.BlockSpec((1, bs, sb), _blk_map),
-                     pl.BlockSpec((1, bs, pb), _blk_map),
-                     pl.BlockSpec((1, bs, sb), _blk_map)]
+        in_specs += [pl.BlockSpec((1, bs, pb), _blk),
+                     pl.BlockSpec((1, bs, sb), _blk),
+                     pl.BlockSpec((1, bs, pb), _blk),
+                     pl.BlockSpec((1, bs, sb), _blk)]
         operands += [pool_k.payload, pool_k.scales,
                      pool_v.payload, pool_v.scales]
-    in_specs.append(pl.BlockSpec((1, Sq), _row_map))
-    operands.append(q_pos.astype(jnp.int32))
+    in_specs.append(pl.BlockSpec((1, SqG, 1), _row))
+    operands.append(jnp.repeat(q_pos.astype(jnp.int32), G, axis=1)[..., None])
     if has_extra:
         E = k_extra.shape[0]
         t_extra = jnp.broadcast_to(t_extra.astype(jnp.int32), (R, E))
-        in_specs += [pl.BlockSpec((E, kv_dim), _whole_map),
-                     pl.BlockSpec((E, kv_dim), _whole_map),
-                     pl.BlockSpec((1, E), _row_map)]
-        operands += [k_extra, v_extra, t_extra]
+        in_specs += [pl.BlockSpec((E, kv_dim), _whole),
+                     pl.BlockSpec((E, kv_dim), _whole),
+                     pl.BlockSpec((1, 1, E), _row)]
+        operands += [k_extra, v_extra, t_extra[:, None, :]]
 
     kernel = functools.partial(
-        _kernel, spec=spec, kv_heads=kv_heads, head_dim=hd, q_heads=H,
-        seq_q=Sq, block_size=bs, n_blocks=nb, scale=scale, window=window,
-        has_extra=has_extra, has_row_map=has_rm)
+        _kernel, spec=spec, kv_heads=kv_heads, head_dim=hd, block_size=bs,
+        n_blocks=nb, scale=scale, window=window, has_extra=has_extra,
+        has_row_map=has_rm)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3 if has_rm else 2,
         grid=(R, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Sq, q_dim), _q_map),
+        out_specs=pl.BlockSpec((1, kv_heads, SqG, hd), _row4),
         scratch_shapes=[
-            pltpu.VMEM((kv_heads, Sq * G), jnp.float32),       # running max
-            pltpu.VMEM((kv_heads, Sq * G), jnp.float32),       # running denom
-            pltpu.VMEM((kv_heads, Sq * G, hd), jnp.float32),   # accumulator
+            pltpu.VMEM((kv_heads, SqG, 1), jnp.float32),       # running max
+            pltpu.VMEM((kv_heads, SqG, 1), jnp.float32),       # running denom
+            pltpu.VMEM((kv_heads, SqG, hd), jnp.float32),      # accumulator
         ],
     )
     prefetch = (tables.astype(jnp.int32), hist_len.astype(jnp.int32))
     if has_rm:
         prefetch += (row_map.astype(jnp.int32),)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, Sq, q_dim), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((R, kv_heads, SqG, hd), out_dtype),
         interpret=interpret,
     )(*prefetch, *operands)
+    out = out.reshape(R, kv_heads, Sq, G, hd).transpose(0, 2, 1, 3, 4)
+    return out.reshape(R, Sq, q_dim)

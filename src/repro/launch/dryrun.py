@@ -24,7 +24,6 @@ from typing import Optional
 import jax
 
 from repro.analysis.roofline import analyze_compiled
-from repro.compat import set_mesh
 from repro.configs import ASSIGNED, INPUT_SHAPES, get_config
 from repro.core.policy import CompressionPolicy, NO_COMPRESSION, PAPER_DEFAULT
 from repro.launch.mesh import make_production_mesh
@@ -85,7 +84,7 @@ def lower_combo(arch: str, shape_name: str, *, multi_pod: bool = False,
     batch_sds = input_shardings(ctx, model.input_specs(shape))
 
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             from repro.training.optimizer import OptState, init_opt_state
 

@@ -44,9 +44,15 @@ hogging a slot (docs/serving.md §Failure modes).
 ``serving/faults.py`` for the grammar) and wraps the run in an
 ``EngineSupervisor`` that detects engine death / wire corruption / stuck
 steps, rebuilds the pools, and replays unfinished requests with backoff.
+
+Parameters are initialised under jit straight into their TP shards (no
+device ever holds the whole model), and compiled programs persist in JAX's
+compilation cache: ``JAX_COMPILATION_CACHE_DIR`` where set, else
+``.jax_cache/`` at the checkout root.
 """
 import argparse
 import time
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -54,8 +60,9 @@ import numpy as np
 from repro.configs import get_config, reduced_config
 from repro.core.formats import MXSpec
 from repro.core.policy import CompressionPolicy, NO_COMPRESSION
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_kv_mesh
-from repro.launch.sharding import make_context
+from repro.launch.sharding import init_params_sharded, make_context
 from repro.models.frontends import audio_frames_stub, patch_embed_stub
 from repro.models.model import Model
 from repro.serving import Engine, EngineSupervisor, FaultPlan, Request
@@ -142,6 +149,7 @@ def main():
                          "contract, dtype drift, host transfers; DESIGN.md "
                          "§Static analysis) and fail fast on any violation")
     args = ap.parse_args()
+    enable_compile_cache(Path(__file__).resolve().parents[3])
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -162,7 +170,7 @@ def main():
     print(f"devices={n_dev} policy={policy.describe()}"
           + (f" kv_shards={ctx.kv_shards}" if ctx.kv_sharded else ""))
 
-    params = model.init_params(jax.random.PRNGKey(0))
+    params = init_params_sharded(model, ctx, jax.random.PRNGKey(0))
     max_len = args.prompt_len + args.new_tokens + cfg.n_patches * (
         cfg.frontend == "vision")
     fault_plan = FaultPlan.parse(args.fault_plan, seed=args.seed)

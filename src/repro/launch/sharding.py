@@ -18,7 +18,8 @@ from repro.configs.base import InputShape
 from repro.core.policy import CompressionPolicy, NO_COMPRESSION
 from repro.core.tp import TPContext
 
-__all__ = ["make_context", "input_shardings"]
+__all__ = ["make_context", "input_shardings", "param_shardings",
+           "init_params_sharded"]
 
 
 def make_context(
@@ -84,6 +85,26 @@ def resolve_specs(shapes_tree, specs_tree, mesh):
         resolve_one, shapes_tree, specs_tree,
         is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct),
     )
+
+
+def param_shardings(model, ctx: TPContext):
+    """NamedSharding pytree for ``model``'s parameters from
+    ``Model.param_specs`` (placements that don't divide a dim dropped);
+    None without a mesh."""
+    if ctx.mesh is None:
+        return None
+    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    specs = resolve_specs(shapes, model.param_specs(ctx), ctx.mesh)
+    return jax.tree.map(lambda s: NamedSharding(ctx.mesh, s), specs,
+                        is_leaf=lambda x: isinstance(x, P))
+
+
+def init_params_sharded(model, ctx: TPContext, key: jax.Array):
+    """Random parameters initialised under jit straight into their
+    shardings: each device computes and holds only its own shards, so no
+    device ever holds the whole model."""
+    return jax.jit(model.init_params,
+                   out_shardings=param_shardings(model, ctx))(key)
 
 
 def input_shardings(ctx: TPContext, specs: Dict) -> Dict:

@@ -99,7 +99,8 @@ def _attend_block(q, k, v, q_pos, t_pos, *, causal, window, scale, kv_heads):
     kh = k.reshape(B, T, KV, hd)
     vh = v.reshape(B, T, KV, hd)
     qg = q.reshape(B, Sq, KV, G, hd)
-    scores = jnp.einsum("bsngd,btnd->bnsgt", qg, kh).astype(jnp.float32) * scale
+    scores = jnp.einsum("bsngd,btnd->bnsgt", qg, kh,
+                        preferred_element_type=jnp.float32) * scale
     tp = t_pos[:, None, :] if t_pos.ndim == 2 else t_pos[None, :]
     if causal:
         valid = tp <= q_pos[..., :, None]
@@ -223,6 +224,7 @@ def _paged_attend_kernel(q, pool_k, pool_v, tables, hist_len, q_pos,
     and the return is (R, Sq, H*hd) in q's dtype. ``row_map`` switches the
     block-table walk to virtual-region addressing over an exchanged pool
     (sequence-sharded read path)."""
+    from repro.kernels.ops import interpret_mode
     from repro.kernels.paged_attention import paged_attention
 
     R, Sq = q.shape[:2]
@@ -231,7 +233,7 @@ def _paged_attend_kernel(q, pool_k, pool_v, tables, hist_len, q_pos,
         k_extra, v_extra, t_extra, row_map,
         spec=cache_spec.mx, kv_heads=cfg.n_kv_heads,
         scale=cfg.head_dim**-0.5, window=window, out_dtype=q.dtype,
-        interpret=jax.default_backend() == "cpu")
+        interpret=interpret_mode())
 
 
 def quantize_kv_pages(k: jnp.ndarray, v: jnp.ndarray, spec) -> tuple:

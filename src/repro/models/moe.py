@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.core.collectives import psum_maybe_compressed
 from repro.core.tp import TPContext, constrain
@@ -119,7 +118,7 @@ def _expert_ffn(ctx: TPContext, params, expert_in: jnp.ndarray,
         return out.reshape(1, E, C, d)
 
     e_entry = data_axes if len(data_axes) > 1 else data_axes[0]
-    return shard_map(
+    return jax.shard_map(
         island,
         mesh=ctx.mesh,
         in_specs=(

@@ -61,6 +61,7 @@ from repro.core.policy import NO_COMPRESSION
 from repro.core.tp import (
     TPContext, constrain, pool_block_copy, pool_block_fill, pool_block_write,
 )
+from repro.launch.sharding import param_shardings
 from repro.models.attention import constrain_wire_pool, quantize_kv_pages
 from repro.models.model import Model
 from repro.serving.errors import (
@@ -94,6 +95,8 @@ class Request:
     cancelled: bool = False       # set via cancel(); swept at the next step
     # filled by the engine:
     output: Optional[np.ndarray] = None
+    first_logits: Optional[np.ndarray] = None  # (V,) float32, with
+                                               # Engine(keep_first_logits=)
     ttft_s: Optional[float] = None
     latency_s: Optional[float] = None
     timing: Optional[RequestTiming] = None
@@ -211,6 +214,15 @@ class Engine:
       ``thrash_window`` / ``thrash_limit`` (eviction-storm guard: bounded
       preemptions per step, with sustained thrash degrading the engine to
       one chunk per step and no admissions until a retire).
+    - ``check_finite`` — check every sampled row's logits for non-finite
+      values (``WireCorruption`` on the first), at one small device->host
+      read per step; always on under a fault plan that corrupts pools.
+    - ``keep_first_logits`` — copy each request's first-token logits row
+      (the prefill's last position) to ``Request.first_logits``, for
+      comparing read paths or formats on the same prompts.
+
+    Parameters are placed on the context's mesh by ``Model.param_specs``
+    (a no-op for parameters already placed so, and without a mesh).
 
     ``run(requests)`` serves a list of ``Request``s and fills their
     ``output``/``ttft_s``/``latency_s``/``timing`` (``timing.outcome`` is
@@ -242,11 +254,15 @@ class Engine:
                  stall_limit: int = 256,
                  max_preempts_per_step: Optional[int] = None,
                  thrash_window: int = 8,
-                 thrash_limit: Optional[int] = None):
+                 thrash_limit: Optional[int] = None,
+                 check_finite: bool = False,
+                 keep_first_logits: bool = False):
         self.model = model
         self.cfg = model.cfg
         self.ctx = ctx
-        self.params = params
+        shardings = param_shardings(model, ctx)
+        self.params = (params if shardings is None
+                       else jax.device_put(params, shardings))
         if max_slots is not None and max_slots <= 0:
             raise SlotExhausted(
                 f"max_slots must be >= 1 (every request needs a decode "
@@ -310,8 +326,9 @@ class Engine:
         # non-finite logits watch (WireCorruption detection) costs one tiny
         # program + a device->host read per step: on only under a fault plan
         # that can corrupt pool bytes
-        self._nan_watch = fault_plan is not None and any(
-            f.kind == "corrupt" for f in fault_plan.faults)
+        self._nan_watch = check_finite or (fault_plan is not None and any(
+            f.kind == "corrupt" for f in fault_plan.faults))
+        self.keep_first_logits = bool(keep_first_logits)
 
         # right-padding to a bucket is only sound when every layer is
         # attention (causal masking hides trailing pads); recurrent layers
@@ -949,14 +966,18 @@ class Engine:
                            min(w.pos // self.block_size, len(w.hashes))):
                 self.prefix_index.register(w.hashes[j], w.blocks[j])
 
-    def _first_token(self, slot: int, w: _Work, tok: int, now: float) -> None:
+    def _first_token(self, slot: int, w: _Work, tok: int, now: float,
+                     logits, row: int) -> None:
         """Prefill-complete bookkeeping, shared by every prefill flavor
         (final chunk in mixed/split mode, whole-prompt admission): the
-        sampled token ends PREFILLING and is the TTFT endpoint."""
+        sampled token (drawn from ``logits[row]``) ends PREFILLING and is
+        the TTFT endpoint."""
         w.prefilling = False
         self._cur[slot] = tok
         if w.first_token_t is None:
             w.first_token_t = now
+            if self.keep_first_logits:
+                w.req.first_logits = np.asarray(logits[row], np.float32)
         w.tokens.append(tok)
         w.token_times.append(now)
         if w.done:
@@ -981,7 +1002,7 @@ class Engine:
         tokens[0, :n_valid] = w.prompt[w.pos:w.pos + n_valid]
         logits, self._state = self._chunk_fn(
             self.params, jnp.asarray(tokens), self._state,
-            jnp.asarray(self._tables[slot]), jnp.int32(w.pos),
+            jnp.array(self._tables[slot]), jnp.int32(w.pos),
             jnp.int32(n_valid))
         self._advance_prefill(slot, w, n_valid)
         if w.pos >= L:
@@ -991,7 +1012,8 @@ class Engine:
             self._key, sub = jax.random.split(self._key)
             temp = jnp.full((1,), w.req.temperature, jnp.float32)
             tok = int(np.asarray(self._sample(logits, temp, sub))[0])
-            self._first_token(slot, w, tok, time.perf_counter() - self._t0)
+            self._first_token(slot, w, tok, time.perf_counter() - self._t0,
+                              logits, 0)
         return n_valid
 
     def _pack_prefill(self, budget: int) -> List:
@@ -1066,11 +1088,15 @@ class Engine:
         gate = (True in self._mixed_fns
                 and self._gate_policy.active_for_step(batch.n_prefill,
                                                       batch.n_decode))
+        # the scheduler's host tables are mutated in place right after this
+        # asynchronous dispatch (lengths advance below), so they go in as
+        # copies: on the CPU backend jnp.asarray may alias the numpy buffer
+        # and the program would race the host's writes
         logits, self._state = self._mixed_fns[gate](
             self.params, jnp.asarray(batch.tokens), self._state,
             jnp.asarray(batch.slot_ids), jnp.asarray(batch.positions),
             jnp.asarray(batch.valid), jnp.asarray(batch.is_decode),
-            jnp.asarray(self._lengths), jnp.asarray(self._tables),
+            jnp.array(self._lengths), jnp.array(self._tables),
             jnp.asarray(batch.sample_idx))
         self.gate_counts["compressed" if gate else "dense"] += 1
         self.stats.record_step(batch.n_prefill, batch.n_decode,
@@ -1098,7 +1124,8 @@ class Engine:
             self._advance_prefill(slot, w, len(chunk))
             if w.pos >= len(w.prompt):
                 # final chunk: its sampled row is the request's first token
-                self._first_token(slot, w, int(toks[slot]), now)
+                self._first_token(slot, w, int(toks[slot]), now, logits,
+                                  slot)
         for slot in decoding:
             w = self._running[slot]
             tok = int(toks[slot])
@@ -1136,7 +1163,7 @@ class Engine:
         if w.admitted_t is None:
             w.admitted_t = now
         self._running[slot] = w
-        self._first_token(slot, w, tok, now)
+        self._first_token(slot, w, tok, now, logits, 0)
 
     def _grow_or_evict(self) -> None:
         """Give every DECODING slot a block covering its next write position,
@@ -1354,8 +1381,8 @@ class Engine:
         block isn't allocated yet — and their sampled token is discarded.
         Returns the number of decode tokens sampled."""
         logits, self._state = self._decode(
-            self.params, jnp.asarray(self._cur[:, None]), self._state,
-            jnp.asarray(self._tables), jnp.asarray(self._lengths))
+            self.params, jnp.array(self._cur[:, None]), self._state,
+            jnp.array(self._tables), jnp.array(self._lengths))
         active = [(s, w) for s, w in self._running.items() if not w.prefilling]
         temps = np.zeros((self.n_slots,), np.float32)
         for slot, w in active:
@@ -1521,6 +1548,22 @@ class Engine:
         times = np.array(times)
         return {"median_s": float(np.median(times)),
                 "std_s": float(np.std(times)), "iters": len(times)}
+
+    def step_program_text(self, *, compressed: bool = False) -> str:
+        """Compiled HLO text of one gate variant of the mixed step program,
+        lowered for this engine's parameters and pools: what the device
+        runs (its kernels appear as ``tpu_custom_call``, its compressed
+        collectives as uint8 all-gathers). Nothing executes."""
+        if compressed not in self._mixed_fns:
+            raise ValueError(f"no {'compressed' if compressed else 'dense'} "
+                             f"mixed step; this engine has "
+                             f"{self.gate_variants() or 'no mixed step'}")
+        T, ns = self.token_budget, self.n_slots
+        i32 = lambda *shape: np.zeros(shape, np.int32)
+        flag = np.zeros((T,), bool)
+        return self._mixed_fns[compressed].lower(
+            self.params, i32(1, T), self._state, i32(T), i32(T), flag, flag,
+            i32(ns), i32(ns, self.max_blocks), i32(ns)).compile().as_text()
 
     # ------------------------------------------------------- static analysis
 

@@ -85,7 +85,7 @@ COLLECTIVE_PRIMITIVES = frozenset({
 # eqns that imply a host round-trip when they appear inside a step program
 HOST_TRANSFER_PRIMITIVES = frozenset({
     "infeed", "outfeed", "host_local_array_to_global_array",
-    "global_array_to_host_local_array", "device_put",
+    "global_array_to_host_local_array", "device_put", "debug_print",
 })
 
 

@@ -12,12 +12,12 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np, re, dataclasses
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro import compat
-from repro.compat import set_mesh
+from jax import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.core import TPContext, row_linear, fused_mlp, PAPER_DEFAULT, NO_COMPRESSION
 from repro.core.policy import CompressionPolicy
 from repro.core.formats import MXSpec
-mesh = compat.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 rng = np.random.default_rng(0)
 x = jnp.asarray(rng.normal(size=(8, 16, 256)), jnp.float32)
 w = jnp.asarray(rng.normal(size=(256, 128)) / 16, jnp.float32)
@@ -201,13 +201,13 @@ def _one_device_island(fn, out_extra_dim=False):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
+    from repro.launch.mesh import make_mesh
 
-    mesh = compat.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     out_specs = P(*((None,) * (3 + int(out_extra_dim))))
-    return compat.shard_map(fn, mesh=mesh, in_specs=P(None, None, None),
-                            out_specs=out_specs, axis_names={"model"},
-                            check_vma=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(None, None, None),
+                         out_specs=out_specs, axis_names={"model"},
+                         check_vma=False)
 
 
 @pytest.mark.parametrize("use_pallas", [False, True])
@@ -372,7 +372,7 @@ def test_compressed_all_gather_roundtrip():
     def f(x):
         def island(xl):
             return compressed_all_gather(xl, "model", spec)
-        return compat.shard_map(island, mesh=mesh, in_specs=P(None, None, "model"),
+        return jax.shard_map(island, mesh=mesh, in_specs=P(None, None, "model"),
                              out_specs=P(None, None, None, "model"),
                              axis_names={"model"}, check_vma=False)(x)
     with set_mesh(mesh):

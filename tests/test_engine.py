@@ -157,6 +157,42 @@ def test_parity_with_single_request_path(small_model):
         np.testing.assert_array_equal(out[i].output, alone.output)
 
 
+def test_first_logits_kept_and_sampled_greedily(small_model):
+    """keep_first_logits copies each request's first-token logits row; with
+    greedy sampling the first output token is that row's argmax."""
+    cfg, model, params = small_model
+    eng = Engine(model, params, CTX, max_slots=2, max_len=64,
+                 cache_dtype=jnp.float32, keep_first_logits=True)
+    out = eng.run([Request(prompt=np.arange(5 + 4 * i, dtype=np.int32),
+                           max_new_tokens=3) for i in range(3)])
+    for r in out:
+        assert r.first_logits.shape == (cfg.vocab_size,)
+        assert int(np.argmax(r.first_logits)) == int(r.output[0])
+    plain = Engine(model, params, CTX, max_slots=2, max_len=64,
+                   cache_dtype=jnp.float32)
+    assert plain.run([Request(prompt=np.arange(5, dtype=np.int32),
+                              max_new_tokens=2)])[0].first_logits is None
+
+
+def test_check_finite_stops_on_nonfinite_logits(small_model):
+    """check_finite turns the sampling-boundary watch on without a fault
+    plan: non-finite logits raise instead of becoming tokens."""
+    from repro.serving import WireCorruption
+
+    cfg, model, params = small_model
+    bad = jax.tree.map(lambda x: x, params)
+    bad["final_norm"]["w"] = bad["final_norm"]["w"].at[0].set(jnp.nan)
+    req = lambda: [Request(prompt=np.arange(6, dtype=np.int32),
+                           max_new_tokens=2)]
+    eng = Engine(model, bad, CTX, max_slots=1, max_len=32,
+                 cache_dtype=jnp.float32, check_finite=True)
+    with pytest.raises(WireCorruption):
+        eng.run(req())
+    unchecked = Engine(model, bad, CTX, max_slots=1, max_len=32,
+                       cache_dtype=jnp.float32)
+    assert len(unchecked.run(req())[0].output) == 2
+
+
 def test_block_freelist_reuse_after_eviction(small_model):
     """Under a deliberately tiny block pool the scheduler preempts
     (evict-and-recompute); evicted blocks return to the free list, get

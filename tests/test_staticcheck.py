@@ -17,9 +17,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.core.policy import PAPER_DEFAULT
 from repro.core.tp import TPContext
+from repro.launch.mesh import make_mesh
 from repro.models.model import Model
 from repro.serving import Engine
 from repro.staticcheck import (
@@ -42,12 +42,12 @@ def small_model():
 def tp_mesh():
     """1-device TP mesh: real 'model' axis semantics (collectives present in
     the jaxpr) without a multi-device runtime — test_collectives.py idiom."""
-    return compat.make_mesh((1,), ("model",))
+    return make_mesh((1,), ("model",))
 
 
 def _tp_engine(model, params, mesh, **kw):
     ctx = TPContext(mesh=mesh, data_axes=(), policy=PAPER_DEFAULT)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return Engine(model, params, ctx, max_slots=2, max_len=64,
                       cache_dtype=jnp.float32, prefill_chunk=8, **kw)
 
@@ -245,12 +245,12 @@ def test_audit_recurses_into_pallas_call(tp_mesh):
         o_ref[...] = jax.lax.psum(x_ref[...], "model")
 
     def prog(x):
-        return compat.shard_map(
+        return jax.shard_map(
             lambda xs: pl.pallas_call(
                 kernel,
                 out_shape=jax.ShapeDtypeStruct((4, 4), jnp.float32),
                 interpret=True)(xs),
-            mesh=tp_mesh, in_specs=P(), out_specs=P())(x)
+            mesh=tp_mesh, in_specs=P(), out_specs=P(), check_vma=False)(x)
 
     jaxpr = jax.make_jaxpr(prog)(jnp.zeros((4, 4), jnp.float32))
     recs = collect_collectives(jaxpr, {"model": 1})
@@ -333,12 +333,12 @@ def test_pool_reshard_allgather_is_red():
 
     from repro.staticcheck.report import ProgramTrace
 
-    kv_mesh = compat.make_mesh((1,), ("kv",))
+    kv_mesh = make_mesh((1,), ("kv",))
     pool = jnp.zeros((8, 16, 4), jnp.float32)
 
     def reshard_findings(body):
-        fn = lambda p: compat.shard_map(body, mesh=kv_mesh,
-                                        in_specs=P(), out_specs=P())(p)
+        fn = lambda p: jax.shard_map(body, mesh=kv_mesh, in_specs=P(),
+                                     out_specs=P(), check_vma=False)(p)
         trace = ProgramTrace(
             name="decode", jaxpr=jax.make_jaxpr(fn)(pool), policy=None,
             n_tokens=1, compute_dtype="float32", is_step=True,
@@ -482,9 +482,9 @@ def test_audit_on_multidevice_tp_mesh():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import dataclasses, jax, jax.numpy as jnp
-        from repro import compat
         from repro.configs import get_config, reduced_config
         from repro.core.policy import PAPER_DEFAULT
+        from repro.launch.mesh import make_mesh
         from repro.launch.sharding import make_context
         from repro.models.model import Model
         from repro.serving import Engine
@@ -494,9 +494,9 @@ def test_audit_on_multidevice_tp_mesh():
                                   dtype="float32")
         model = Model(cfg)
         params = model.init_params(jax.random.PRNGKey(0))
-        mesh = compat.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = make_context(mesh, None, policy=PAPER_DEFAULT)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             eng = Engine(model, params, ctx, max_slots=2, max_len=64,
                          cache_dtype=jnp.float32, cache_spec="fp4_e2m1",
                          prefill_chunk=8)
