@@ -7,9 +7,14 @@ byte per block), dequantize all shards locally and reduce with a sum.
 All functions here run *inside* shard_map-manual code (they take an
 ``axis_name``). The TP-island wrappers that embed them into a GSPMD program
 live in ``repro.core.tp``.
+
+Every MX quantize and dequantize of a collective here, jnp or Pallas, runs
+under the ``tp_codec`` named scope, so a device trace credits the codec's
+time to one name; the collectives themselves stay outside it.
 """
 from __future__ import annotations
 
+import functools
 import math
 import warnings
 from typing import Optional
@@ -122,8 +127,20 @@ def _gather_staged(comps, axis_name: str):
     ]
 
 
+TP_CODEC_SCOPE = "tp_codec"
+
+
+def _in_codec_scope(fn):
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope(TP_CODEC_SCOPE):
+            return fn(*args, **kwargs)
+    return scoped
+
+
 def _codec(use_pallas: bool):
-    """Return (quantize, dequantize) implementations.
+    """Return (quantize, dequantize) implementations, each under the
+    ``tp_codec`` named scope.
 
     The Pallas kernels are drop-in replacements for the pure-jnp codec with
     identical semantics (tests assert bit-exactness). On CPU we run them in
@@ -132,8 +149,9 @@ def _codec(use_pallas: bool):
     if use_pallas:
         from repro.kernels import ops  # local import: kernels are optional
 
-        return ops.mx_quantize, ops.mx_dequantize
-    return mx.quantize, mx.dequantize
+        return (_in_codec_scope(ops.mx_quantize),
+                _in_codec_scope(ops.mx_dequantize))
+    return _in_codec_scope(mx.quantize), _in_codec_scope(mx.dequantize)
 
 
 def compressed_all_gather(
@@ -184,8 +202,9 @@ def _gathered_reduce(
         # fused decompress+sum epilogue: one VMEM pass over the shards
         from repro.kernels import ops
 
-        total = ops.mx_dequant_reduce(MXCompressed(payload, scales), spec,
-                                      out_dtype=accum_dtype)
+        with jax.named_scope(TP_CODEC_SCOPE):
+            total = ops.mx_dequant_reduce(MXCompressed(payload, scales),
+                                          spec, out_dtype=accum_dtype)
     else:
         # stream the shard accumulation — materializing the dequantized
         # (N, ..., F) fp32 tensor at once would dwarf the activation memory

@@ -179,6 +179,7 @@ def mx_dequantize_2d(
         out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, n), out_dtype),
         interpret=interpret,
+        name="mx_dequantize",
     )(_pad_rows(payload, 0, mp), _pad_rows(scales, 0, mp))
     return out[:m]
 
@@ -208,5 +209,6 @@ def dequant_reduce(
         out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, n), out_dtype),
         interpret=interpret,
+        name="mx_dequantize_reduce",
     )(_pad_rows(payload, 1, mp), _pad_rows(scales, 1, mp))
     return out[:m]

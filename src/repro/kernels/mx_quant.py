@@ -131,5 +131,6 @@ def mx_quantize_2d(
             jax.ShapeDtypeStruct((mp, n // spec.block_size), jnp.uint8),
         ),
         interpret=interpret,
+        name="mx_quantize",
     )(_pad_rows(x, 0, mp))
     return payload[:m], scales[:m]

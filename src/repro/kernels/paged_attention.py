@@ -264,6 +264,7 @@ def paged_attention(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, kv_heads, SqG, hd), out_dtype),
         interpret=interpret,
+        name="paged_attention",
     )(*prefetch, *operands)
     out = out.reshape(R, kv_heads, Sq, G, hd).transpose(0, 2, 1, 3, 4)
     return out.reshape(R, Sq, q_dim)

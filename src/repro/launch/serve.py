@@ -257,6 +257,13 @@ def main():
     print(f"dispatch: {s['n_steps']} steps, {s['n_dispatches']} program "
           f"dispatches, {s['tokens_per_step_mean']:.1f} tokens/step "
           f"({s['prefill_tokens']} prefill + {s['decode_tokens']} decode)")
+    if s["n_steps"]:
+        # the engine's step-phase spans (docs/serving.md "Observability"):
+        # host time a step outside the device wait is
+        # admit+schedule+dispatch+emit
+        print("host ms/step: " + ", ".join(
+            f"{p} {stats_src.phase_s.get('engine.' + p, 0.0) / s['n_steps'] * 1e3:.2f}"
+            for p in ("admit", "schedule", "dispatch", "wait", "emit")))
     if "compressed" in engine.gate_variants():
         print(f"compression gate: {s['n_compressed_steps']} compressed / "
               f"{s['n_steps'] - s['n_compressed_steps']} dense steps")

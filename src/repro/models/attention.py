@@ -35,6 +35,9 @@ __all__ = ["init_attention", "KVCache", "init_cache", "attention",
 
 NEG_INF = -1e30
 _Q_CHUNK = 1024
+# named scope of the mixed step's paged attention (history read, attention,
+# pool append): what a device trace credits that work to
+PAGED_ATTENTION_SCOPE = "paged_attention"
 
 
 class KVCache(NamedTuple):
@@ -599,14 +602,35 @@ def paged_attention_mixed(
     the reserved null block. Shapes depend only on (token_budget, n_slots,
     max_blocks), so the engine compiles this exactly once.
 
+    Everything between the QKV and output projections (the history read,
+    the attention, the pool append) runs under the ``paged_attention``
+    named scope, whichever read path serves it, so a device trace credits
+    that work to one name.
+
     Returns (out (1, T, d_model), pool_k, pool_v).
     """
     B, T = x.shape[:2]
+    q, k_new, v_new = _qkv(ctx, params, x, cfg, positions[None, :])
+    with jax.named_scope(PAGED_ATTENTION_SCOPE):
+        out, pool_k, pool_v = _mixed_read_attend_append(
+            ctx, cfg, q, k_new, v_new, positions=positions,
+            slot_ids=slot_ids, slot_starts=slot_starts, valid=valid,
+            is_decode=is_decode, tables=tables, pool_k=pool_k,
+            pool_v=pool_v, window=window, cache_spec=cache_spec)
+    y = row_linear(ctx, out, params["wo"]["w"], n_tokens=B * T)
+    return y, pool_k, pool_v
+
+
+def _mixed_read_attend_append(ctx: TPContext, cfg: ModelConfig, q, k_new,
+                              v_new, *, positions, slot_ids, slot_starts,
+                              valid, is_decode, tables, pool_k, pool_v,
+                              window, cache_spec):
+    """The body of ``paged_attention_mixed`` past its QKV projection:
+    returns (attention out (1, T, H*hd), pool_k, pool_v)."""
+    T = q.shape[1]
     a = ctx.axis if ctx.tp else None
     scale = cfg.head_dim**-0.5
     quantized = cache_spec is not None and cache_spec.quantized
-
-    q, k_new, v_new = _qkv(ctx, params, x, cfg, positions[None, :])
     qt = q[0][:, None]                                  # (T, 1, H, hd)
 
     my_tables = tables[slot_ids]                        # (T, max_blocks)
@@ -723,10 +747,7 @@ def paged_attention_mixed(
             pool_v = pool_v.at[blk, offs].set(v_new[0].astype(pool_v.dtype))
         pool_k = constrain(ctx, pool_k, _kv_entry(ctx), None, a)
         pool_v = constrain(ctx, pool_v, _kv_entry(ctx), None, a)
-
-    out = constrain(ctx, out, ctx.batch, None, a)
-    y = row_linear(ctx, out, params["wo"]["w"], n_tokens=B * T)
-    return y, pool_k, pool_v
+    return constrain(ctx, out, ctx.batch, None, a), pool_k, pool_v
 
 
 def attention_specs(cfg: ModelConfig, ctx: TPContext):

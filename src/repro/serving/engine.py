@@ -1002,7 +1002,7 @@ class Engine:
         tokens[0, :n_valid] = w.prompt[w.pos:w.pos + n_valid]
         logits, self._state = self._chunk_fn(
             self.params, jnp.asarray(tokens), self._state,
-            jnp.array(self._tables[slot]), jnp.int32(w.pos),
+            jnp.asarray(self._tables[slot].copy()), jnp.int32(w.pos),
             jnp.int32(n_valid))
         self._advance_prefill(slot, w, n_valid)
         if w.pos >= L:
@@ -1065,75 +1065,87 @@ class Engine:
         """One unified engine step: pack prefill chunks + the decode batch
         into a single flattened token-budget program dispatch, then sample
         every slot that produced a token this step. Returns the number of
-        real tokens processed (0: every slot deferred)."""
-        self._grow_or_evict()
-        decoding = sorted(s for s, w in self._running.items()
-                          if not w.prefilling)
-        # decode tokens are reserved FIRST (never dropped for prefill work;
-        # token_budget >= n_slots guarantees they fit), prefill chunks pack
-        # into the remainder
-        segs = self._pack_prefill(self.token_budget - len(decoding))
-        # eviction during packing may have preempted decode slots
-        decoding = [s for s in decoding if s in self._running]
-        if not segs and not decoding:
-            return 0  # every prefilling slot deferred; decodes free blocks
-        batch = build_mixed_batch(
-            segs, [(s, int(self._cur[s]), int(self._lengths[s]))
-                   for s in decoding],
-            self.token_budget, self.n_slots)
+        real tokens processed (0: every slot deferred). Its four phases are
+        disjoint spans (docs/serving.md "Observability")."""
+        span = self.stats.span
+        with span("engine.schedule"):
+            self._grow_or_evict()
+            decoding = sorted(s for s, w in self._running.items()
+                              if not w.prefilling)
+            # decode tokens are reserved FIRST (never dropped for prefill
+            # work; token_budget >= n_slots guarantees they fit), prefill
+            # chunks pack into the remainder
+            segs = self._pack_prefill(self.token_budget - len(decoding))
+            # eviction during packing may have preempted decode slots
+            decoding = [s for s in decoding if s in self._running]
+            if not segs and not decoding:
+                return 0  # every prefilling slot deferred; decodes free blocks
+            batch = build_mixed_batch(
+                segs, [(s, int(self._cur[s]), int(self._lengths[s]))
+                       for s in decoding],
+                self.token_budget, self.n_slots)
 
-        # per-step compression gate on the batch's REAL composition
-        # (n_prefill/n_decode count valid tokens, never padding): dispatch
-        # the pre-compiled variant; no shape changes, so no recompile
-        gate = (True in self._mixed_fns
-                and self._gate_policy.active_for_step(batch.n_prefill,
-                                                      batch.n_decode))
-        # the scheduler's host tables are mutated in place right after this
-        # asynchronous dispatch (lengths advance below), so they go in as
-        # copies: on the CPU backend jnp.asarray may alias the numpy buffer
-        # and the program would race the host's writes
-        logits, self._state = self._mixed_fns[gate](
-            self.params, jnp.asarray(batch.tokens), self._state,
-            jnp.asarray(batch.slot_ids), jnp.asarray(batch.positions),
-            jnp.asarray(batch.valid), jnp.asarray(batch.is_decode),
-            jnp.array(self._lengths), jnp.array(self._tables),
-            jnp.asarray(batch.sample_idx))
-        self.gate_counts["compressed" if gate else "dense"] += 1
-        self.stats.record_step(batch.n_prefill, batch.n_decode,
-                               n_dispatches=1, compressed=gate)
+            # per-step compression gate on the batch's REAL composition
+            # (n_prefill/n_decode count valid tokens, never padding):
+            # dispatch the pre-compiled variant; no shape changes, so no
+            # recompile
+            gate = (True in self._mixed_fns
+                    and self._gate_policy.active_for_step(batch.n_prefill,
+                                                          batch.n_decode))
+        with span("engine.dispatch"):
+            # the scheduler's host tables are mutated in place right after
+            # this asynchronous dispatch (lengths advance below), so they go
+            # in as host copies taken now: on the CPU backend the transfer
+            # may read its numpy buffer after the call returns, and even
+            # jnp.array's copy then races the host's writes
+            logits, self._state = self._mixed_fns[gate](
+                self.params, jnp.asarray(batch.tokens), self._state,
+                jnp.asarray(batch.slot_ids), jnp.asarray(batch.positions),
+                jnp.asarray(batch.valid), jnp.asarray(batch.is_decode),
+                jnp.asarray(self._lengths.copy()),
+                jnp.asarray(self._tables.copy()),
+                jnp.asarray(batch.sample_idx))
+            self.gate_counts["compressed" if gate else "dense"] += 1
+            self.stats.record_step(batch.n_prefill, batch.n_decode,
+                                   n_dispatches=1, compressed=gate)
 
-        # one sample over all slots; non-sampling rows are garbage/discarded
-        temps = np.zeros((self.n_slots,), np.float32)
-        for slot, _, _ in segs:
-            temps[slot] = self._running[slot].req.temperature
-        for slot in decoding:
-            self._lengths[slot] += 1
-            temps[slot] = self._running[slot].req.temperature
-        self._key, sub = jax.random.split(self._key)
-        # corruption watch runs before ANY host state absorbs this step's
-        # tokens, so a supervisor replay never sees poisoned output
-        self._check_finite(logits, decoding + [
-            slot for slot, chunk, _ in segs
-            if self._running[slot].pos + len(chunk)
-            >= len(self._running[slot].prompt)])
-        toks = np.asarray(self._sample(logits, jnp.asarray(temps), sub))
-        now = time.perf_counter() - self._t0
+        with span("engine.wait"):
+            # one sample over all slots; non-sampling rows are
+            # garbage/discarded
+            temps = np.zeros((self.n_slots,), np.float32)
+            for slot, _, _ in segs:
+                temps[slot] = self._running[slot].req.temperature
+            for slot in decoding:
+                self._lengths[slot] += 1
+                temps[slot] = self._running[slot].req.temperature
+            self._key, sub = jax.random.split(self._key)
+            # corruption watch runs before ANY host state absorbs this
+            # step's tokens, so a supervisor replay never sees poisoned
+            # output
+            self._check_finite(logits, decoding + [
+                slot for slot, chunk, _ in segs
+                if self._running[slot].pos + len(chunk)
+                >= len(self._running[slot].prompt)])
+            toks = np.asarray(self._sample(logits, jnp.asarray(temps), sub))
 
-        for slot, chunk, _ in segs:
-            w = self._running[slot]
-            self._advance_prefill(slot, w, len(chunk))
-            if w.pos >= len(w.prompt):
-                # final chunk: its sampled row is the request's first token
-                self._first_token(slot, w, int(toks[slot]), now, logits,
-                                  slot)
-        for slot in decoding:
-            w = self._running[slot]
-            tok = int(toks[slot])
-            w.tokens.append(tok)
-            w.token_times.append(now)
-            self._cur[slot] = tok
-            if w.done:
-                self._retire(slot, now)
+        with span("engine.emit"):
+            now = time.perf_counter() - self._t0
+            for slot, chunk, _ in segs:
+                w = self._running[slot]
+                self._advance_prefill(slot, w, len(chunk))
+                if w.pos >= len(w.prompt):
+                    # final chunk: its sampled row is the request's first
+                    # token
+                    self._first_token(slot, w, int(toks[slot]), now, logits,
+                                      slot)
+            for slot in decoding:
+                w = self._running[slot]
+                tok = int(toks[slot])
+                w.tokens.append(tok)
+                w.token_times.append(now)
+                self._cur[slot] = tok
+                if w.done:
+                    self._retire(slot, now)
         return batch.n_prefill + batch.n_decode
 
     def _admit(self, w: _Work, slot: int, ids: List[int]) -> None:
@@ -1381,8 +1393,9 @@ class Engine:
         block isn't allocated yet — and their sampled token is discarded.
         Returns the number of decode tokens sampled."""
         logits, self._state = self._decode(
-            self.params, jnp.array(self._cur[:, None]), self._state,
-            jnp.array(self._tables), jnp.array(self._lengths))
+            self.params, jnp.asarray(self._cur[:, None].copy()), self._state,
+            jnp.asarray(self._tables.copy()),
+            jnp.asarray(self._lengths.copy()))
         active = [(s, w) for s, w in self._running.items() if not w.prefilling]
         temps = np.zeros((self.n_slots,), np.float32)
         for slot, w in active:
@@ -1441,43 +1454,49 @@ class Engine:
                                extra=extra, arrival=float(r.arrival_s)))
         self._waiting = sorted(works, key=lambda w: w.arrival)
 
+        span = self.stats.span
         try:
             while self._waiting or self._running:
                 now = time.perf_counter() - self._t0
-                self._sweep_terminal(now)
-                self._admit_ready(now)
-                self._bound_queue(now)
+                with span("engine.admit"):
+                    self._sweep_terminal(now)
+                    self._admit_ready(now)
+                    self._bound_queue(now)
                 if not self._running:
-                    if self._waiting:
-                        time.sleep(min(max(self._waiting[0].arrival - now,
-                                           0.0), 0.005))
+                    with span("engine.idle"):
+                        if self._waiting:
+                            time.sleep(min(max(self._waiting[0].arrival - now,
+                                               0.0), 0.005))
                     continue
                 self._step_i += 1
                 self._step_preempts = 0
-                t_step = time.perf_counter()
-                if self.fault_plan is not None:
-                    self._apply_faults()
-                if self.token_budget:
-                    # unified step: packed prefill chunks + the decode batch
-                    # in ONE program dispatch (DESIGN.md §Mixed step)
-                    n_tok = self._step_mixed()
-                else:
-                    # split scheduler: (at most) one prefill chunk, then a
-                    # batched decode for every live DECODING slot — kills
-                    # head-of-line blocking like the mixed step, at two
-                    # dispatches per step
-                    n_pref = self._prefill_step() if self.prefill_chunk else 0
-                    self._grow_or_evict()
-                    n_dec = 0
-                    if any(not w.prefilling
-                           for w in self._running.values()):
-                        n_dec = self._decode_once()
-                    self.stats.record_step(
-                        n_pref, n_dec,
-                        n_dispatches=(1 if n_pref else 0)
-                        + (1 if n_dec else 0))
-                    n_tok = n_pref + n_dec
-                self._guard_step(n_tok, time.perf_counter() - t_step)
+                # the step's span is its one timer: the watchdog reads it
+                with span("engine.step", step_num=self._step_i) as step:
+                    if self.fault_plan is not None:
+                        self._apply_faults()
+                    if self.token_budget:
+                        # unified step: packed prefill chunks + the decode
+                        # batch in ONE program dispatch (DESIGN.md §Mixed
+                        # step)
+                        n_tok = self._step_mixed()
+                    else:
+                        # split scheduler: (at most) one prefill chunk, then
+                        # a batched decode for every live DECODING slot —
+                        # kills head-of-line blocking like the mixed step,
+                        # at two dispatches per step
+                        n_pref = (self._prefill_step() if self.prefill_chunk
+                                  else 0)
+                        self._grow_or_evict()
+                        n_dec = 0
+                        if any(not w.prefilling
+                               for w in self._running.values()):
+                            n_dec = self._decode_once()
+                        self.stats.record_step(
+                            n_pref, n_dec,
+                            n_dispatches=(1 if n_pref else 0)
+                            + (1 if n_dec else 0))
+                        n_tok = n_pref + n_dec
+                self._guard_step(n_tok, step.seconds)
         finally:
             # fault holds never outlive a run: whether it completed, timed
             # every request out, or is about to be supervised through a

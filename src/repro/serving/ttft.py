@@ -20,14 +20,17 @@ benchmarks/table3_ttft.py.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Dict, List, Optional
+
+import jax
 
 from repro.configs.base import ModelConfig
 from repro.core.formats import MXSpec
 from repro.serving.errors import OUTCOME_OK, TERMINAL_OUTCOMES
 
 __all__ = ["Hardware", "HARDWARE", "ttft_seconds", "ttft_breakdown",
-           "RequestTiming", "ServeStats"]
+           "RequestTiming", "ServeStats", "Span"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -184,6 +187,35 @@ def _percentile(xs: List[float], p: float) -> float:
     return xs[i]
 
 
+class Span:
+    """One timed span of the engine loop (``ServeStats.span``): a
+    ``jax.profiler`` trace annotation on the profiler's clock, whose
+    ``time.perf_counter`` length is added to the owner's ``phase_s`` /
+    ``phase_n`` on exit (also on an exception) and kept in ``seconds``."""
+
+    __slots__ = ("_stats", "_name", "_ann", "_t", "seconds")
+
+    def __init__(self, stats: "ServeStats", name: str,
+                 step_num: Optional[int]):
+        self._stats, self._name = stats, name
+        self._ann = (jax.profiler.TraceAnnotation(name) if step_num is None
+                     else jax.profiler.StepTraceAnnotation(
+                         name, step_num=step_num))
+        self.seconds = 0.0
+
+    def __enter__(self) -> "Span":
+        self._ann.__enter__()
+        self._t = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = time.perf_counter() - self._t
+        self._ann.__exit__(*exc)
+        st = self._stats
+        st.phase_s[self._name] = st.phase_s.get(self._name, 0.0) + self.seconds
+        st.phase_n[self._name] = st.phase_n.get(self._name, 0) + 1
+
+
 class ServeStats:
     """Aggregates ``RequestTiming`` records across one serving run.
 
@@ -211,6 +243,17 @@ class ServeStats:
         # prompt tokens processed outside budgeted steps (whole-prompt
         # prefill at admission)
         self.off_step_prefill_tokens = 0
+        # host seconds and counts per span name (``span``): ``engine.admit``,
+        # ``engine.step`` and its disjoint phases, ``engine.idle``
+        # (docs/serving.md "Observability")
+        self.phase_s: Dict[str, float] = {}
+        self.phase_n: Dict[str, int] = {}
+
+    def span(self, name: str, step_num: Optional[int] = None) -> Span:
+        """Context manager timing one ``name`` span into ``phase_s`` /
+        ``phase_n``; with ``step_num`` it is a ``StepTraceAnnotation``, the
+        key a profiler trace shares with ``n_steps``."""
+        return Span(self, name, step_num)
 
     def record(self, t: RequestTiming) -> None:
         self.timings.append(t)
@@ -247,6 +290,10 @@ class ServeStats:
         self.step_tokens.extend(other.step_tokens)
         self.n_compressed_steps += other.n_compressed_steps
         self.off_step_prefill_tokens += other.off_step_prefill_tokens
+        for name, sec in other.phase_s.items():
+            self.phase_s[name] = self.phase_s.get(name, 0.0) + sec
+        for name, n in other.phase_n.items():
+            self.phase_n[name] = self.phase_n.get(name, 0) + n
 
     def summary(self) -> Dict[str, float]:
         """Aggregate the run. Keys (seconds unless noted):
