@@ -257,6 +257,9 @@ def main():
     print(f"dispatch: {s['n_steps']} steps, {s['n_dispatches']} program "
           f"dispatches, {s['tokens_per_step_mean']:.1f} tokens/step "
           f"({s['prefill_tokens']} prefill + {s['decode_tokens']} decode)")
+    if stats_src.read_tiles_held:
+        print(f"history read: {s['read_tile_share']:.3f} of the mixed "
+              f"step's read tiles hold a query")
     if s["n_steps"]:
         # the engine's step-phase spans (docs/serving.md "Observability"):
         # host time a step outside the device wait is

@@ -31,13 +31,49 @@ from repro.models.common import Initializer, apply_rope, init_linear, make_rope,
 
 __all__ = ["init_attention", "KVCache", "init_cache", "attention",
            "attention_specs", "paged_attention_decode", "paged_attention_chunk",
-           "paged_attention_mixed", "quantize_kv_pages"]
+           "paged_attention_mixed", "quantize_kv_pages", "READ_TILE",
+           "mixed_read_tiles", "read_tiles_held", "read_tiles_used"]
 
 NEG_INF = -1e30
 _Q_CHUNK = 1024
 # named scope of the mixed step's paged attention (history read, attention,
 # pool append): what a device trace credits that work to
 PAGED_ATTENTION_SCOPE = "paged_attention"
+# query rows per tile of the mixed step's jnp history read: the f32 sublane
+# count, so one tile's scores against a history fill whole vector registers
+READ_TILE = 8
+
+
+def mixed_read_tiles(token_budget: int, n_slots: int) -> int:
+    """Static tile count of the mixed step's jnp history read: the most
+    tiles that any packing of ``token_budget`` rows into at most ``n_slots``
+    one-slot segments fills. A segment of n rows fills ceil(n / L) tiles, and
+    sum_s ceil(n_s / L) <= (sum_s n_s + n_slots * (L - 1)) // L; no tile is
+    empty, so it never exceeds the rows either."""
+    return min(token_budget,
+               (token_budget + n_slots * (READ_TILE - 1)) // READ_TILE)
+
+
+def _pallas_read(cache_spec) -> bool:
+    """Whether the Pallas kernel serves the mixed step's history read (each
+    row walks its own slot's table) instead of the tiled jnp gather."""
+    return cache_spec is not None and cache_spec.use_pallas
+
+
+def read_tiles_held(token_budget: int, n_slots: int, cache_spec) -> int:
+    """Tiles the mixed step's history read holds each step under
+    ``cache_spec``: ``mixed_read_tiles`` for the jnp read, 0 where the Pallas
+    kernel serves it."""
+    if _pallas_read(cache_spec):
+        return 0
+    return mixed_read_tiles(token_budget, n_slots)
+
+
+def read_tiles_used(segment_lengths) -> int:
+    """Tiles of the history read that a step's packing fills: ceil(n / L)
+    for each slot's segment of n rows (a decode token is a segment of one),
+    the rule ``_read_tiles`` applies inside the program."""
+    return sum(-(-n // READ_TILE) for n in segment_lengths)
 
 
 class KVCache(NamedTuple):
@@ -87,14 +123,11 @@ def _qkv(ctx: TPContext, params, x, cfg: ModelConfig, positions):
 
 
 def _attend_block(q, k, v, q_pos, t_pos, *, causal, window, scale, kv_heads):
-    """q (B,Sq,H,hd); k/v flat (B,T,kv_dim); t_pos (T,) or (B,T). ->
-    (B,Sq,H*hd).
+    """q (B,Sq,H,hd); k/v flat (B,T,kv_dim); t_pos (T,) key positions,
+    shared across the batch. -> (B,Sq,H*hd).
 
     q_pos is (Sq,) when positions are shared across the batch, or (B,Sq)
-    for per-slot positions (continuous-batching decode). t_pos is (T,) when
-    key positions are shared across the batch, or (B,T) when each batch row
-    attends its own gathered sequence (the mixed token-budget step, where
-    every flattened token is its own batch row)."""
+    for per-slot positions (continuous-batching decode)."""
     B, Sq, H, hd = q.shape
     T = k.shape[1]
     KV = kv_heads
@@ -104,7 +137,7 @@ def _attend_block(q, k, v, q_pos, t_pos, *, causal, window, scale, kv_heads):
     qg = q.reshape(B, Sq, KV, G, hd)
     scores = jnp.einsum("bsngd,btnd->bnsgt", qg, kh,
                         preferred_element_type=jnp.float32) * scale
-    tp = t_pos[:, None, :] if t_pos.ndim == 2 else t_pos[None, :]
+    tp = t_pos[None, :]
     if causal:
         valid = tp <= q_pos[..., :, None]
     else:
@@ -586,21 +619,27 @@ def paged_attention_mixed(
     one decode token per DECODING slot, flattened into a single (1, T) batch
     and attended against the paged cache in one program.
 
-    Every flattened token becomes its own attention batch row: token t
-    reads ITS slot's paged history through ``tables[slot_ids[t]]`` (valid
-    below ``slot_starts[slot_ids[t]]`` — everything written before this
-    step; a jnp gather, or a gather-free block-table walk under
-    ``cache_spec.use_pallas``), and additionally attends the current
-    batch's same-slot tokens at
-    positions <= its own. Precision mirrors the split chunk/decode pair
-    exactly: prefill tokens see same-chunk neighbours in COMPUTE precision
-    (what ``paged_attention_chunk`` did), while a decode token sees its own
+    Token t attends ITS slot's paged history through
+    ``tables[slot_ids[t]]`` (valid below ``slot_starts[slot_ids[t]]`` —
+    everything written before this step) and the current batch's same-slot
+    tokens at positions <= its own. The jnp read gathers each slot's history
+    once per query TILE, not once per token: a slot's rows form one
+    contiguous segment, cut into tiles of ``READ_TILE`` rows, and the
+    ``mixed_read_tiles(T, n_slots)`` tiles each gather their slot's history
+    (``pool[tables[tile_slot]]``), score their rows against it and apply the
+    probabilities to it; scores travel between tiles and rows by each row's
+    (tile, lane). Under ``cache_spec.use_pallas`` each row instead walks its
+    slot's table in the gather-free kernel. Precision mirrors the split
+    chunk/decode pair exactly: prefill tokens see same-chunk neighbours in
+    COMPUTE precision (what ``paged_attention_chunk`` did), while a decode
+    token sees its own
     just-written K/V at POOL precision (dense-dtype cast or MX round-trip —
     what ``paged_attention_decode`` reads back after its scatter). All new
     K/V is then appended into the pools through the shared
-    ``quantize_kv_pages`` codec entry; pad rows (``valid`` False) write into
-    the reserved null block. Shapes depend only on (token_budget, n_slots,
-    max_blocks), so the engine compiles this exactly once.
+    ``quantize_kv_pages`` codec entry; pad rows (``valid`` False) read zero
+    and write into the reserved null block. Shapes depend only on
+    (token_budget, n_slots, max_blocks), so the engine compiles this exactly
+    once.
 
     Everything between the QKV and output projections (the history read,
     the attention, the pool append) runs under the ``paged_attention``
@@ -619,6 +658,96 @@ def paged_attention_mixed(
             pool_v=pool_v, window=window, cache_spec=cache_spec)
     y = row_linear(ctx, out, params["wo"]["w"], n_tokens=B * T)
     return y, pool_k, pool_v
+
+
+def _read_tiles(slot_ids, valid, n_slots: int):
+    """Map the mixed step's flattened rows onto read tiles, inside the
+    program. ``build_mixed_batch`` lays each slot's rows out as one
+    contiguous segment; slot s's n_s rows fill ceil(n_s / READ_TILE) tiles,
+    numbered slot by slot, and its i-th row sits in the segment's tile
+    i // READ_TILE at lane i % READ_TILE. Pad rows get tile N, which
+    scatters drop. Returns (tile (T,), lane (T,), tile_slot (N,)) with N =
+    ``mixed_read_tiles(T, n_slots)``; tiles no row fills read slot 0."""
+    T = slot_ids.shape[0]
+    n = mixed_read_tiles(T, n_slots)
+    rows = jnp.arange(T, dtype=jnp.int32)
+    seg_len = jax.ops.segment_sum(valid.astype(jnp.int32), slot_ids,
+                                  num_segments=n_slots)
+    seg_off = jax.ops.segment_min(jnp.where(valid, rows, T), slot_ids,
+                                  num_segments=n_slots)
+    seg_tiles = -(-seg_len // READ_TILE)
+    first_tile = jnp.cumsum(seg_tiles) - seg_tiles
+    i = jnp.where(valid, rows - seg_off[slot_ids], 0)
+    tile = jnp.where(valid, first_tile[slot_ids] + i // READ_TILE, n)
+    tile_slot = jnp.zeros((n,), jnp.int32).at[tile].set(slot_ids, mode="drop")
+    return tile, i % READ_TILE, tile_slot
+
+
+def _attend_tiled(q, k_hist, v_hist, k_step, v_step, tile, lane, q_pos,
+                  t_hist, t_step, *, window, scale, kv_heads, kv_groups):
+    """Attention of the mixed step's T rows over each row's slot history,
+    held once per tile, plus the step's own K/V. q (T, H, hd); k/v_hist
+    (N, cap, kv_dim), tile n holding slot ``tile_slot[n]``'s history; k/v_step
+    (T, kv_dim); row t sits in ``tile[t]`` at ``lane[t]`` (pads: tile N);
+    t_hist (T, cap) and t_step (T, T) are each row's key positions, invalid
+    keys at ``_T_INVALID``. History scores are made per tile and brought
+    back to rows; the step's scores are made per row; one softmax in f32
+    runs over all cap + T of a row's scores, and its probabilities, cast to
+    the value dtype, weigh the history per tile and the step's values per
+    row, both summed in f32. Returns (T, H*hd) in the value dtype.
+
+    The kv heads split into ``kv_groups`` groups of contiguous heads, one
+    per tensor-parallel shard of kv_dim. The history einsums take the groups
+    as a batch dimension, which the partitioner splits, and the heads of a
+    group one at a time, each on its lane slice of the flat history: with
+    every head a batch dimension, XLA relays the whole gathered history out
+    before the einsum."""
+    T, H, hd = q.shape
+    n_tiles, cap = k_hist.shape[:2]
+    G = H // kv_heads
+    local = kv_heads // kv_groups
+    f32 = jnp.float32
+    qg = q.reshape(T, kv_groups, local, G, hd)
+    kh = k_hist.reshape(n_tiles, cap, kv_groups, local * hd)
+    vh = v_hist.reshape(n_tiles, cap, kv_groups, local * hd)
+    # pad rows read whichever tile is last; the caller zeroes their outputs
+    at = (jnp.minimum(tile, n_tiles - 1), lane)
+
+    def tiled(rows):                  # (T, ...) -> (N, READ_TILE, ...)
+        x = jnp.zeros((n_tiles, READ_TILE) + rows.shape[1:], rows.dtype)
+        return x.at[tile, lane].set(rows, mode="drop")
+
+    def head(x, j):                   # head j of each group: its lanes
+        return x[..., j * hd:(j + 1) * hd]
+
+    q_tiles = tiled(qg)
+    s_hist = jnp.stack(
+        [jnp.einsum("nlmgd,ncmd->nlmgc", q_tiles[:, :, :, j], head(kh, j),
+                    preferred_element_type=f32)[at]
+         for j in range(local)], axis=2).reshape(T, kv_heads, G, cap)
+    s_step = jnp.einsum("tkgd,skd->tkgs", qg.reshape(T, kv_heads, G, hd),
+                        k_step.reshape(T, kv_heads, hd),
+                        preferred_element_type=f32)
+
+    def keep(t_pos):                  # (T, X) key positions -> (T, 1, 1, X)
+        ok = t_pos <= q_pos[:, None]
+        if window is not None:
+            ok = ok & (t_pos > q_pos[:, None] - window)
+        return ok[:, None, None, :]
+
+    scores = jnp.concatenate(
+        [jnp.where(keep(t_hist), s_hist * scale, NEG_INF),
+         jnp.where(keep(t_step), s_step * scale, NEG_INF)], axis=-1)
+    probs = jax.nn.softmax(scores, axis=-1).astype(v_hist.dtype)
+    p_hist = probs[..., :cap].reshape(T, kv_groups, local, G, cap)
+    out = jnp.stack(
+        [jnp.einsum("nlmgc,ncmd->nlmgd", tiled(p_hist[:, :, j]),
+                    head(vh, j), preferred_element_type=f32)[at]
+         for j in range(local)], axis=2).reshape(T, kv_heads, G, hd)
+    out = out + jnp.einsum("tkgs,skd->tkgd", probs[..., cap:],
+                           v_step.reshape(T, kv_heads, hd),
+                           preferred_element_type=f32)
+    return out.reshape(T, H * hd).astype(probs.dtype)
 
 
 def _mixed_read_attend_append(ctx: TPContext, cfg: ModelConfig, q, k_new,
@@ -665,10 +794,10 @@ def _mixed_read_attend_append(ctx: TPContext, cfg: ModelConfig, q, k_new,
         vpool_k, vpool_v = _virtual_pools(ctx, pool_k, pool_v, tables,
                                           quantized)
 
-    if cache_spec is not None and cache_spec.use_pallas:
+    if _pallas_read(cache_spec):
         # gather-free read: each flattened token walks its OWN slot's table
-        # row in the kernel — the O(T * cap) pool[my_tables] HBM gather the
-        # jnp path below pays never materializes. The step's in-batch K/V
+        # row in the kernel — the pool[tables[tile_slot]] HBM gather of the
+        # jnp path below never materializes. The step's in-batch K/V
         # rides along as extras with the (T, T) same-slot position mask.
         out = _paged_attend_kernel(
             qt, vpool_k if ctx.kv_sharded else pool_k,
@@ -679,44 +808,33 @@ def _mixed_read_attend_append(ctx: TPContext, cfg: ModelConfig, q, k_new,
     else:
         t_hist = jnp.arange(cap, dtype=jnp.int32)[None, :]      # (1, cap)
         t_hist = jnp.where(t_hist < start[:, None], t_hist, _T_INVALID)
-        if quantized:
-            if ctx.kv_sharded:
-                # per-slot virtual regions -> per-token rows: a gather over
-                # the (n_slots, cap, wire) exchange buffer, never the pool
-                k_wire = MXCompressed(
-                    vpool_k.payload.reshape(tables.shape[0], cap, -1)[slot_ids],
-                    vpool_k.scales.reshape(tables.shape[0], cap, -1)[slot_ids])
-                v_wire = MXCompressed(
-                    vpool_v.payload.reshape(tables.shape[0], cap, -1)[slot_ids],
-                    vpool_v.scales.reshape(tables.shape[0], cap, -1)[slot_ids])
-            else:
-                k_wire = MXCompressed(
-                    pool_k.payload[my_tables].reshape(T, cap, -1),
-                    pool_k.scales[my_tables].reshape(T, cap, -1))
-                v_wire = MXCompressed(
-                    pool_v.payload[my_tables].reshape(T, cap, -1),
-                    pool_v.scales[my_tables].reshape(T, cap, -1))
-            k_hist = mx.dequantize(k_wire, mxs, out_dtype=q.dtype)
-            v_hist = mx.dequantize(v_wire, mxs, out_dtype=q.dtype)
-        elif ctx.kv_sharded:
-            ns = tables.shape[0]
-            k_hist = vpool_k.reshape(ns, cap, -1)[slot_ids].astype(q.dtype)
-            v_hist = vpool_v.reshape(ns, cap, -1)[slot_ids].astype(q.dtype)
-        else:
-            k_hist = pool_k[my_tables].reshape(T, cap, -1).astype(q.dtype)
-            v_hist = pool_v[my_tables].reshape(T, cap, -1).astype(q.dtype)
+        ns = tables.shape[0]
+        tile, lane, tile_slot = _read_tiles(slot_ids, valid, ns)
+        tile_tables = tables[tile_slot]
 
-        k_all = jnp.concatenate(
-            [k_hist, jnp.broadcast_to(k_step[None], (T,) + k_step.shape)],
-            axis=1)
-        v_all = jnp.concatenate(
-            [v_hist, jnp.broadcast_to(v_step[None], (T,) + v_step.shape)],
-            axis=1)
-        t_pos = jnp.concatenate([t_hist, t_step], axis=1)       # (T, cap+T)
-        out = _attend_block(qt, k_all, v_all, positions[:, None], t_pos,
-                            causal=True, window=window, scale=scale,
-                            kv_heads=cfg.n_kv_heads)
+        def per_tile(arr):            # one history per tile: (N, cap, width)
+            if ctx.kv_sharded:        # the exchange's per-slot regions
+                return arr.reshape(ns, cap, -1)[tile_slot]
+            return arr[tile_tables].reshape(tile_slot.shape[0], cap, -1)
+
+        pk, pv = (vpool_k, vpool_v) if ctx.kv_sharded else (pool_k, pool_v)
+        if quantized:
+            k_hist = mx.dequantize(MXCompressed(*map(per_tile, pk)), mxs,
+                                   out_dtype=q.dtype)
+            v_hist = mx.dequantize(MXCompressed(*map(per_tile, pv)), mxs,
+                                   out_dtype=q.dtype)
+        else:
+            k_hist = per_tile(pk).astype(q.dtype)
+            v_hist = per_tile(pv).astype(q.dtype)
+        kv = cfg.n_kv_heads
+        out = _attend_tiled(
+            q[0], k_hist, v_hist, k_step, v_step, tile, lane, positions,
+            t_hist, t_step, window=window, scale=scale, kv_heads=kv,
+            kv_groups=ctx.tp_size if kv % ctx.tp_size == 0 else kv)[:, None]
     out = out[:, 0][None]                               # (1, T, H*hd)
+    # pad rows attend nothing real: both reads give them zeros, so what the
+    # next layer appends into the null block is finite and the same
+    out = jnp.where(valid[None, :, None], out, 0)
 
     # append every real token's K/V into the pools; pads fall into the null
     # block. Same codec entry + constrain discipline as the split writers.

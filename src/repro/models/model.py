@@ -359,10 +359,11 @@ class Model:
         valid token of its prefill segment; 0/garbage for slots that don't
         sample this step).
 
-        Per attention layer ``paged_attention_mixed`` gathers each token's
-        slot history from the paged pools, attends it together with the
-        same-slot tokens of the current batch (split-path precision
-        semantics preserved token class by token class), and appends all
+        Per attention layer ``paged_attention_mixed`` reads each token's
+        slot history from the paged pools (the jnp read once per tile of
+        same-slot tokens), attends it together with the same-slot tokens
+        of the current batch (split-path precision semantics preserved
+        token class by token class), and appends all
         new K/V into the pools. Shapes depend only on
         ``(token_budget, n_slots, max_blocks)``, so the engine compiles
         this exactly once — one program dispatch per step where the split

@@ -62,7 +62,9 @@ from repro.core.tp import (
     TPContext, constrain, pool_block_copy, pool_block_fill, pool_block_write,
 )
 from repro.launch.sharding import param_shardings
-from repro.models.attention import constrain_wire_pool, quantize_kv_pages
+from repro.models.attention import (
+    constrain_wire_pool, quantize_kv_pages, read_tiles_held, read_tiles_used,
+)
 from repro.models.model import Model
 from repro.serving.errors import (
     OUTCOME_CANCELLED, OUTCOME_OK, OUTCOME_REJECTED, OUTCOME_TIMED_OUT,
@@ -382,6 +384,9 @@ class Engine:
                 f"{self.prefill_chunk}); shrink prefill_chunk for a "
                 f"smaller step")
         self.token_budget = int(token_budget)
+        # tiles the mixed step's history read holds each step
+        self._read_tiles_held = read_tiles_held(
+            self.token_budget, self.n_slots, self.cache_spec)
 
         # automatic prefix caching (DESIGN.md §Prefix caching): full prompt
         # blocks are published in a hash-chain index and mapped by reference
@@ -1106,8 +1111,13 @@ class Engine:
                 jnp.asarray(self._tables.copy()),
                 jnp.asarray(batch.sample_idx))
             self.gate_counts["compressed" if gate else "dense"] += 1
-            self.stats.record_step(batch.n_prefill, batch.n_decode,
-                                   n_dispatches=1, compressed=gate)
+            self.stats.record_step(
+                batch.n_prefill, batch.n_decode, n_dispatches=1,
+                compressed=gate,
+                read_tiles=(read_tiles_used(
+                    [len(toks) for _, toks, _ in segs] + [1] * len(decoding))
+                    if self._read_tiles_held else 0),
+                read_tiles_held=self._read_tiles_held)
 
         with span("engine.wait"):
             # one sample over all slots; non-sampling rows are

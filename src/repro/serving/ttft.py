@@ -243,6 +243,10 @@ class ServeStats:
         # prompt tokens processed outside budgeted steps (whole-prompt
         # prefill at admission)
         self.off_step_prefill_tokens = 0
+        # tiles of the mixed step's jnp history read that hold a real row,
+        # and the tiles the fixed-shape read holds, summed over steps
+        self.read_tiles = 0
+        self.read_tiles_held = 0
         # host seconds and counts per span name (``span``): ``engine.admit``,
         # ``engine.step`` and its disjoint phases, ``engine.idle``
         # (docs/serving.md "Observability")
@@ -259,17 +263,22 @@ class ServeStats:
         self.timings.append(t)
 
     def record_step(self, n_prefill: int, n_decode: int,
-                    n_dispatches: int = 1, compressed: bool = False) -> None:
+                    n_dispatches: int = 1, compressed: bool = False,
+                    read_tiles: int = 0, read_tiles_held: int = 0) -> None:
         """One engine step: ``n_prefill`` prompt tokens + ``n_decode``
         decode tokens processed through ``n_dispatches`` device programs
         (1 for the unified mixed step; up to 2 — chunk + decode — for the
         split scheduler). ``compressed`` marks a step dispatched through
-        the compressed gate variant of the mixed program."""
+        the compressed gate variant of the mixed program. ``read_tiles`` of
+        the ``read_tiles_held`` tiles of the mixed step's jnp history read
+        hold a real row."""
         self.n_steps += 1
         self.n_dispatches += n_dispatches
         self.step_tokens.append((n_prefill, n_decode))
         if compressed:
             self.n_compressed_steps += 1
+        self.read_tiles += read_tiles
+        self.read_tiles_held += read_tiles_held
 
     def record_dispatch(self, n: int = 1, prefill_tokens: int = 0) -> None:
         """Off-step program dispatches (whole-prompt prefill + insert at
@@ -290,6 +299,8 @@ class ServeStats:
         self.step_tokens.extend(other.step_tokens)
         self.n_compressed_steps += other.n_compressed_steps
         self.off_step_prefill_tokens += other.off_step_prefill_tokens
+        self.read_tiles += other.read_tiles
+        self.read_tiles_held += other.read_tiles_held
         for name, sec in other.phase_s.items():
             self.phase_s[name] = self.phase_s.get(name, 0.0) + sec
         for name, n in other.phase_n.items():
@@ -315,6 +326,9 @@ class ServeStats:
           program per step where the split scheduler paid two).
         - ``n_compressed_steps`` — steps dispatched through the compressed
           mixed-program gate variant (per-step composition gating).
+        - ``read_tile_share`` — tiles of the mixed step's jnp history read
+          that hold a real row, over the tiles its fixed shape holds (steps
+          x ``mixed_read_tiles``); 0.0 where no step took that read.
         - ``n_preemptions`` — evict-and-recompute round trips.
         - ``prefill_tokens_skipped`` — prompt tokens served from shared
           prefix-cache blocks instead of recomputed; ``prefix_hit_rate``
@@ -362,6 +376,8 @@ class ServeStats:
             "n_steps": self.n_steps,
             "n_dispatches": self.n_dispatches,
             "n_compressed_steps": self.n_compressed_steps,
+            "read_tile_share": (self.read_tiles / self.read_tiles_held
+                                if self.read_tiles_held else 0.0),
             "tokens_per_step_mean": (step_total / self.n_steps
                                      if self.n_steps else 0.0),
             "prefill_tokens": (sum(p for p, _ in self.step_tokens)
