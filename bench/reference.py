@@ -1,5 +1,11 @@
 """Plain float32 reference for the dense GQA decoders (internlm2, qwen2).
 
+A configuration file names its reference module (``"reference"``); the
+harness asks it for three things: ``program_widths`` (the program-config
+attributes the configuration fixes), ``model_flops`` (the model FLOPs a
+token costs, for ``step_mfu``) and ``reference_gaps`` (the check that
+decides ``correct``). This module is the one for dense GQA decoders.
+
 Follows the published architecture: token embedding, then per layer
 RMSNorm -> attention (RoPE, rotate-half form; grouped KV heads; optional
 QKV bias) -> residual, RMSNorm -> SwiGLU MLP -> residual; final RMSNorm and
@@ -37,7 +43,8 @@ import numpy as np
 
 from bench.weights import base_key, leaf, leaf_from_key, leaf_key
 
-__all__ = ["Sizes", "reference_logits", "reference_gaps"]
+__all__ = ["Sizes", "program_widths", "model_flops", "reference_logits",
+           "reference_gaps"]
 
 F8_MAX = 448.0
 
@@ -69,6 +76,34 @@ class Sizes:
         if self.qkv_bias:
             s.update({"core/wq/b": (q,), "core/wk/b": (kv,), "core/wv/b": (kv,)})
         return s
+
+
+def program_widths(sizes: dict) -> dict:
+    """The program-config attributes (``repro.configs.ModelConfig``) that
+    the configuration's published ``sizes`` fix, with their values."""
+    s = sizes
+    return {"d_model": s["hidden_size"], "d_ff": s["intermediate_size"],
+            "n_heads": s["num_attention_heads"],
+            "n_kv_heads": s["num_key_value_heads"], "head_dim": s["head_dim"],
+            "n_layers": s["num_hidden_layers"], "vocab_size": s["vocab_size"],
+            "rope_theta": s["rope_theta"], "qkv_bias": s["qkv_bias"],
+            "tie_embeddings": s["tie_word_embeddings"],
+            "dtype": s["torch_dtype"]}
+
+
+def model_flops(sizes: dict) -> tuple:
+    """(FLOPs per real token, FLOPs per emitted token): two a weight of
+    every layer's matrices for each token the step processes, two a weight
+    of the output head for each token it samples (the step computes logits
+    only for sampled rows). Attention's score and value products are left
+    out (a few percent at the cells' lengths), so ``step_mfu`` is a lower
+    bound."""
+    s = sizes
+    d, ff = s["hidden_size"], s["intermediate_size"]
+    hd = s.get("head_dim") or d // s["num_attention_heads"]
+    q, kv = s["num_attention_heads"] * hd, s["num_key_value_heads"] * hd
+    per_layer = d * q + 2 * d * kv + q * d + 3 * d * ff
+    return 2 * per_layer * s["num_hidden_layers"], 2 * s["vocab_size"] * d
 
 
 def _f8(x, axis):

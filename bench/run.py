@@ -3,8 +3,12 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell names a configuration (``bench/configs/<file>``) and a traffic mix
-(``bench/traffic/<mix>.json``); its correctness check and limit are in
-``bench/cells/<cell>.json``. The run:
+(``bench/traffic/<mix>.json``); its correctness check and limit, the named
+scopes its trace reads and any override of the configuration's
+compression ``policy`` are in ``bench/cells/<cell>.json``. The
+configuration names its architecture's reference module (``"reference"``,
+a file under the checkout root), which gives the widths the program must
+have, the model FLOPs a token costs and the reference check. The run:
 
 1. turns on JAX's persistent compile cache inside the checkout;
 2. makes the weights on the device from ``--seed`` (``bench/weights.py``);
@@ -13,12 +17,19 @@ The cell names a configuration (``bench/configs/<file>``) and a traffic mix
 4. drives ``Engine.run`` with the mix's seeded schedule for ``--seconds``,
    then drains (open loop) or stops (backlog), cutting what is unfinished;
 5. frees the program's state and checks a sample of the served tokens
-   against the float32 reference (``bench/reference.py``).
+   against the configuration's float32 reference.
 
 ``--trace 0`` prints the cell's end-to-end metrics, ``--trace 1`` its
 per-layer metrics (a profiler trace of a few seconds in mid-window) and a
-breakdown. The last stdout line is the result JSON; the numbers compared
-for ``correct`` are the last lines on stderr and the ``check`` key.
+breakdown. A metric reader ``bench/metrics/<name>.py`` gets the run's
+record: ``run["stats"]`` holds every counter of the engine's ``ServeStats``
+at the window's close, ``run["stats_before_trace"]`` the same from the window's
+start to the profiler's start (the close's snapshot waits for the profiler
+to stop, so in a traced run it comes late), ``run["traced"]["stats"]``
+their change over the traced seconds, ``run["trace"]`` the trace's reduction
+(``bench/trace_reduce.py``) with the cell's named scopes. The last stdout
+line is the result JSON; the numbers compared for ``correct`` are the last
+lines on stderr and the ``check`` key.
 Without a TPU, or with fewer chips than the cell asks for, it exits
 non-zero and prints no result.
 """
@@ -61,15 +72,24 @@ def load_cell(name: str, root: Path = ROOT) -> dict:
     if cell is None:
         raise SystemExit(f"unknown workload {name!r}; BENCHMARK.json has "
                          f"{[w['name'] for w in spec['workloads']]}")
-    conf = next(c for c in spec["configs"] if c["name"] == cell["config"])
+    entry = next(c for c in spec["configs"] if c["name"] == cell["config"])
+    conf = json.loads((root / entry["file"]).read_text())
+    if not conf.get("reference"):
+        raise SystemExit(f"bench: configuration {entry['file']} names no "
+                         f"reference module (key \"reference\")")
+    ref_path = root / conf["reference"]
+    check = json.loads((root / "bench" / "cells" / f"{name}.json").read_text())
     return {
         "cell": cell,
         "spec": spec,
-        "config": json.loads((root / conf["file"]).read_text()),
+        "config": conf,
+        "reference": load_file(ref_path, f"bench_reference_{ref_path.stem}"),
+        # a cell may serve its configuration under another policy (null:
+        # dense collectives)
+        "policy": check["policy"] if "policy" in check else conf.get("policy"),
         "mix": traffic_mod.load_mix(
             root / "bench" / "traffic" / f"{cell['traffic']}.json"),
-        "check": json.loads(
-            (root / "bench" / "cells" / f"{name}.json").read_text()),
+        "check": check,
     }
 
 
@@ -86,12 +106,18 @@ def metrics_for(spec: dict, cell: str, kind: str) -> list:
             if "workloads" not in m or cell in m["workloads"]]
 
 
-def read_metric(name: str, run: dict):
-    path = BENCH / "metrics" / f"{name}.py"
-    mod_spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
+def load_file(path: Path, name: str):
+    """The Python file at ``path``, loaded as module ``name``."""
+    mod_spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(mod_spec)
+    sys.modules[name] = mod
     mod_spec.loader.exec_module(mod)
+    return mod
+
+
+def read_metric(name: str, run: dict):
+    mod = load_file(BENCH / "metrics" / f"{name}.py",
+                    f"bench_metric_{name.replace('.', '_')}")
     value = mod.read(run)
     return None if value is None else float(value)
 
@@ -134,21 +160,15 @@ def build(loaded: dict, seed: int, *, policy_elem=None, model_cfg=None):
 
     conf = loaded["config"]
     cfg = model_cfg or get_config(conf["model"])
-    s = conf["sizes"]
-    want = {"d_model": s["hidden_size"], "d_ff": s["intermediate_size"],
-            "n_heads": s["num_attention_heads"],
-            "n_kv_heads": s["num_key_value_heads"], "head_dim": s["head_dim"],
-            "n_layers": s["num_hidden_layers"], "vocab_size": s["vocab_size"],
-            "rope_theta": s["rope_theta"], "qkv_bias": s["qkv_bias"],
-            "tie_embeddings": s["tie_word_embeddings"], "dtype": s["torch_dtype"]}
+    want = loaded["reference"].program_widths(conf["sizes"])
     got = {k: getattr(cfg, k) for k in want}
     if got != want:
         raise SystemExit(f"bench: the program's {conf['model']} is not the "
                          f"configuration file's: {got} != {want}")
     model = Model(cfg)
     pol = NO_COMPRESSION
-    if conf.get("policy"):
-        p = conf["policy"]
+    if loaded["policy"]:
+        p = loaded["policy"]
         pol = CompressionPolicy(
             spec=MXSpec.make(policy_elem or p["elem"], p["block_size"],
                              p["scale"]),
@@ -182,11 +202,35 @@ def warm_up(engine, vocab: int):
     engine._state = None
 
 
+def counters_of(stats) -> dict:
+    """Every number and every dict of numbers among the attributes of the
+    engine's ``ServeStats``, copied; its per-step and per-request lists are
+    left out."""
+    out = {}
+    for k, v in list(vars(stats).items()):
+        if isinstance(v, dict):
+            v = dict(v)
+            if all(isinstance(x, (int, float)) for x in v.values()):
+                out[k] = v
+        elif isinstance(v, (int, float)):
+            out[k] = v
+    return out
+
+
+def counters_change(a: dict, b: dict) -> dict:
+    """``counters_of`` snapshot ``b`` less the earlier snapshot ``a``."""
+    return {k: ({n: x - a.get(k, {}).get(n, 0) for n, x in v.items()}
+                if isinstance(v, dict) else v - a.get(k, 0))
+            for k, v in b.items()}
+
+
 class Monitor(threading.Thread):
-    """Side thread on the engine's clock: snapshots the step counters at the
-    window's close, cuts what is unfinished (at the close for a backlog, at
-    the end of the drain for an open loop), and traces a few seconds of
-    mid-window when asked."""
+    """Side thread on the engine's clock: snapshots the engine's counters
+    at the window's close, cuts what is unfinished (at the close for a
+    backlog, at the end of the drain for an open loop), and traces a few
+    seconds of mid-window when asked, snapshotting the counters just before
+    the profiler starts. The close's snapshot waits for ``stop_trace``, so
+    in a traced run it can come late."""
 
     def __init__(self, engine, requests, seconds, drain, open_loop,
                  trace_dir=None, trace_at=None, trace_len=0.0):
@@ -197,13 +241,14 @@ class Monitor(threading.Thread):
                                                          trace_len)
         self.prev_t0 = getattr(engine, "_t0", None)
         self.done = threading.Event()
-        self.snap_close = self.snap_trace = None
+        self.snap_close = self.snap_trace = self.snap_untraced = None
+        self.stop_s = None
         self.t0 = None
         self.error = None
 
     def _snap(self):
         st = self.engine.stats
-        return (st.n_steps, list(st.step_tokens), st.n_compressed_steps)
+        return counters_of(st), list(st.step_tokens)
 
     def _wait_until(self, t):
         while not self.done.is_set():
@@ -223,12 +268,15 @@ class Monitor(threading.Thread):
                 import jax
 
                 from bench.trace_reduce import MONITOR_SPAN
+                self.snap_untraced = counters_of(self.engine.stats)
                 jax.profiler.start_trace(str(self.trace_dir))
                 with jax.profiler.TraceAnnotation(MONITOR_SPAN):
                     a = (self._snap(), time.perf_counter() - self.t0)
                     self._wait_until(self.trace_at + self.trace_len)
                     b = (self._snap(), time.perf_counter() - self.t0)
+                t_stop = time.perf_counter()
                 jax.profiler.stop_trace()
+                self.stop_s = time.perf_counter() - t_stop
                 self.snap_trace = (a, b)
             if self._wait_until(self.seconds) or self.done.is_set():
                 self.snap_close = self._snap()
@@ -302,6 +350,10 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     if rate is not None:
         mix = traffic_mod.Mix(**{**mix.__dict__, "rate_per_s": float(rate)})
     enable_compile_cache(root)
+    # key compiled programs on their op names too: the trace readers find
+    # named scopes there, and a program loaded from a tree whose scopes
+    # differ would carry that tree's names
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     compiles = []
     jax.monitoring.register_event_duration_secs_listener(
         lambda ev, dur, **kw: compiles.append(
@@ -338,16 +390,20 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
 
     recs = records_of(requests)
     win = window_metrics(recs, seconds, open_loop=mix.open_loop)
-    n_close, steps_close, comp_close = mon.snap_close or (
-        engine.stats.n_steps, list(engine.stats.step_tokens),
-        engine.stats.n_compressed_steps)
+    stats_close, steps_close = mon.snap_close or (
+        counters_of(engine.stats), list(engine.stats.step_tokens))
+    n_close, comp_close = (stats_close["n_steps"],
+                           stats_close["n_compressed_steps"])
+    ref, sizes = loaded["reference"], as_run(conf)
     run = {
         "window": win, "seconds": seconds, "setup_s": setup_s,
-        "chips": cell["chips"], "sizes": as_run(conf),
-        "policy": conf.get("policy"),
+        "chips": cell["chips"], "sizes": sizes,
+        "model_flops": tuple(ref.model_flops(sizes)),
+        "policy": loaded["policy"],
         "steps": {"n": n_close, "budget": engine.token_budget,
                   "real_tokens": sum(p + d for p, d in steps_close),
                   "compressed": comp_close},
+        "stats": stats_close,
     }
     outcomes = [r.outcome for r in recs]
 
@@ -378,15 +434,27 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         from bench.trace_reduce import find_trace, reduce_trace
         path = find_trace(trace_dir) if mon.snap_trace else None
         if path:
-            trace_red = reduce_trace(path, kernel_patterns=chk.get("kernels"))
+            t_red = time.perf_counter()
+            trace_red = reduce_trace(path, kernel_patterns=chk.get("kernels"),
+                                     scopes=chk.get("scopes"))
+            t_red = time.perf_counter() - t_red
             (a_snap, a_t), (b_snap, b_t) = mon.snap_trace
             tok = [t for rec in recs for t in rec.token_times
                    if a_t <= t <= b_t]
+            traced_stats = counters_change(a_snap[0], b_snap[0])
             run["trace"] = trace_red
             run["traced"] = {
                 "real_tokens": sum(p + d for p, d in b_snap[1][len(a_snap[1]):]),
                 "emitted": len(tok),
-                "compressed_steps": b_snap[2] - a_snap[2]}
+                "compressed_steps": traced_stats["n_compressed_steps"],
+                "stats": traced_stats}
+            run["stats_before_trace"] = mon.snap_untraced
+            log(f"named scopes, device seconds a chip in the traced "
+                f"{trace_red.get('window_s')} s: {trace_red.get('scopes')}; "
+                f"first device's idle seconds by host span: "
+                f"{trace_red.get('idle_by_span')}; profiler stopped in "
+                f"{mon.stop_s:.1f} s, trace reduced in {t_red:.1f} s",
+                file=sys.stderr, flush=True)
         run["peak"] = (device_peak(devs[0].device_kind) if not allow_cpu
                        else {"bf16_flops_per_s": 1.0})
         if own_trace_dir:
@@ -403,7 +471,6 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
               else [])
     seqs = [(np.asarray(requests[i].prompt), np.asarray(requests[i].output))
             for i in chosen]
-    from bench.reference import reference_gaps
     # the number compared: the widest gap of a served token below the
     # reference's best, or (where that does not separate the program from
     # its control, see PERF.md) the mean gap over the served tokens
@@ -411,8 +478,9 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     limit = float(chk["limit"])
     res, readings, n_served = {}, {}, 0
     if seqs:
-        res = reference_gaps(as_run(conf), seed, seqs,
-                             conf["engine"]["max_len"], control=control)
+        t_ref = time.perf_counter()
+        res = ref.reference_gaps(sizes, seed, seqs,
+                                 conf["engine"]["max_len"], control=control)
         n_served = int(sum(len(g) for g in res["gaps"]))
         for key, gaps in (("", res["gaps"]),
                           ("control_", res.get("control_gaps"))):
@@ -420,7 +488,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
                 readings[key + "widest_gap"] = float(max(g.max() for g in gaps))
                 readings[key + "mean_gap"] = float(np.mean(np.concatenate(gaps)))
         log(f"gaps over {n_served} served tokens of requests {chosen}: "
-            f"{readings}", file=sys.stderr, flush=True)
+            f"{readings}; reference in {time.perf_counter() - t_ref:.1f} s",
+            file=sys.stderr, flush=True)
     # with ``control`` the float8 reference stands in the program's place:
     # its gaps are the ones judged, so a sound limit makes the run incorrect
     judged = "control_" + compare if control else compare
@@ -450,6 +519,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         result["breakdown"] = {"device_ops": trace_red["device_ops"][:10],
                                "idle_gaps": trace_red["idle_gaps"][:10]}
     result["check"] = check
+    log(f"run ended {time.perf_counter() - T_START:.1f} s after the process "
+        f"started", file=sys.stderr, flush=True)
     for k, v in check.items():
         log(f"check {k}: {v['value']} limit {v['limit']}", file=sys.stderr,
             flush=True)
